@@ -38,7 +38,8 @@ def main():
     mesh = None
     if args.mesh:
         d, m = map(int, args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        from repro.distributed.mesh import make_mesh
+        mesh = make_mesh((d, m), ("data", "model"))
         from repro.models.common import set_activation_sharding
         set_activation_sharding(mesh, ("data",), "model")
 

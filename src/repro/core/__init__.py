@@ -6,7 +6,8 @@ the double-buffered tile scheduler (scheduler.py) and the hardware specs
 (cluster.py).
 """
 from .descriptor import (Agu, Descriptor, Opcode, axpy, gemv, gemm, memcpy,
-                         memset, relu, argmax, laplace1d,
+                         memset, relu, argmax, laplace1d, conv2d,
+                         stencil_pass,
                          hw_steps_to_strides, strides_to_hw_steps,
                          NUM_LOOPS, NUM_AGUS, MAX_HW_COUNT)
 from .engine import execute, execute_vectorized, execute_jax
@@ -28,7 +29,8 @@ from .executor import (ExecutionPolicy, Executor,
 
 __all__ = [
     "Agu", "Descriptor", "Opcode", "axpy", "gemv", "gemm", "memcpy",
-    "memset", "relu", "argmax", "laplace1d", "hw_steps_to_strides",
+    "memset", "relu", "argmax", "laplace1d", "conv2d", "stencil_pass",
+    "hw_steps_to_strides",
     "strides_to_hw_steps", "NUM_LOOPS", "NUM_AGUS", "MAX_HW_COUNT",
     "execute", "execute_vectorized", "execute_jax",
     "NtxClusterSpec", "TpuChipSpec", "PAPER_CLUSTER", "TPU_V5E",

@@ -249,18 +249,36 @@ def gemm(m: int, n: int, k: int, a_base: int, b_base: int, c_base: int) -> Descr
     )
 
 
-def conv2d_3x3_row(w: int, kw: int, kh: int, img_base: int, ker_base: int,
-                   out_base: int, img_w: int) -> Descriptor:
-    """One output row of a 2-D valid convolution (paper §III-B2).
+def conv2d(oh: int, ow: int, kh: int, kw: int, img_base: int, ker_base: int,
+           out_base: int, img_w: int) -> Descriptor:
+    """An (oh, ow) output plane of a 2-D valid convolution (paper §III-B2).
 
-    Loops: (kernel col, kernel row, out col) — 3 of the 5 HWLs; the host
-    (RISC-V / scheduler) iterates output rows and channels.
-    """
+    Loops: (kernel col, kernel row, out col, out row) — the output row loop,
+    which the paper's RISC-V host iterates, taken into the nest as a fourth
+    HWL."""
     return Descriptor(
-        bounds=(kw, kh, w), opcode=Opcode.MAC, init_level=2, store_level=2,
-        agu0=Agu(img_base, (1, img_w, 1)),
-        agu1=Agu(ker_base, (1, kw, 0)),
-        agu2=Agu(out_base, (0, 0, 1)),
+        bounds=(kw, kh, ow, oh), opcode=Opcode.MAC, init_level=2,
+        store_level=2,
+        agu0=Agu(img_base, (1, img_w, 1, img_w)),
+        agu1=Agu(ker_base, (1, kw, 0, 0)),
+        agu2=Agu(out_base, (0, 0, 1, ow)),
+    )
+
+
+def stencil_pass(rows: int, cols: int, k: int, axis: int, x_base: int,
+                 coef_base: int, out_base: int, pitch: int) -> Descriptor:
+    """One k-tap 1-D stencil pass (paper §III-B3's per-dimension
+    decomposition) over a (rows, cols) output window of a plane with row
+    pitch ``pitch``: ``out[r, c] = sum_j coef[j] * x[r, c + j]`` along the
+    row (``axis=1``) or ``x[r + j, c]`` down the column (``axis=0``)."""
+    if axis not in (0, 1):
+        raise ValueError(f"axis must be 0 or 1, got {axis}")
+    return Descriptor(
+        bounds=(k, cols, rows), opcode=Opcode.MAC, init_level=1,
+        store_level=1,
+        agu0=Agu(x_base, (1 if axis == 1 else pitch, 1, pitch)),
+        agu1=Agu(coef_base, (1, 0, 0)),
+        agu2=Agu(out_base, (0, 1, cols)),
     )
 
 

@@ -230,7 +230,11 @@ class Executor:
         stopwatch's pick (the policy-level analogue of the GEMM-block
         ``autotune="measure"`` racing, memoized the same way). Each
         candidate is warmed once so compile/plan time stays out of the
-        timed run; candidates that fail to execute are skipped."""
+        timed run. A candidate the compiler refuses (``ops.LOWERING_ERRORS``
+        while its whole program is compiled once under ``jax.jit``) is
+        recorded under ``"refused"``; if every candidate is refused, the
+        last refusal is raised. Errors of a run propagate."""
+        from repro.kernels import ops
         key = (tuple(descs), self._n_clusters(), self.policy.transport,
                self.policy.backend, self.policy.spec,
                self.policy.setup_cycles, self._mem_spec(),
@@ -238,23 +242,31 @@ class Executor:
         hit = _MEASURED_POLICY.get(key)
         if hit is not None:
             return hit["policy"], {"measured": dict(hit["times_s"]),
+                                   "refused": dict(hit["refused"]),
                                    "measured_cached": True}
         times: Dict[str, float] = {}
-        best, best_t = "serial", float("inf")
+        refused: Dict[str, str] = {}
+        best, best_t, last_err = "serial", float("inf"), None
         for cand in ("serial", "fused", "multistream", "pipeline"):
-            try:
-                runner, _ = self._build_runner(descs, cand)
-                jax.block_until_ready(runner(mem))        # warm: compile
-                t0 = time.perf_counter()
-                jax.block_until_ready(runner(mem))
-                dt = time.perf_counter() - t0
-            except Exception:
+            runner, _ = self._build_runner(descs, cand)
+            _, err = ops.compile_or_refusal(runner, mem)
+            if err is not None:
+                refused[cand] = f"{type(err).__name__}: {err}"
+                last_err = err
                 continue
+            jax.block_until_ready(runner(mem))        # warm: compile
+            t0 = time.perf_counter()
+            jax.block_until_ready(runner(mem))
+            dt = time.perf_counter() - t0
             times[cand] = dt
             if dt < best_t:
                 best, best_t = cand, dt
-        _MEASURED_POLICY[key] = {"policy": best, "times_s": times}
-        return best, {"measured": times, "measured_cached": False}
+        if not times:
+            raise last_err
+        _MEASURED_POLICY[key] = {"policy": best, "times_s": times,
+                                 "refused": refused}
+        return best, {"measured": times, "refused": refused,
+                      "measured_cached": False}
 
     def plan(self, program_or_descs) -> Dict:
         """Resolve the policy for a program without executing it."""

@@ -393,16 +393,18 @@ def _stacked_run_fn(subs: Sequence[SubStream], sharded: bool,
 
     n_lanes = len(subs)
     if sharded:
-        from jax.sharding import Mesh, PartitionSpec as P
-        from repro.distributed.compat import shard_map
+        from jax.sharding import PartitionSpec as P
+        from repro.distributed.mesh import make_mesh
         n_dev = min(len(jax.devices()), n_lanes)
         if stats is not None:
             stats["n_devices_used"] = n_dev
-        mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("cluster",))
+        mesh = make_mesh((n_dev,), ("cluster",))
         pad = (-n_lanes) % n_dev
-        inner = shard_map(lambda w: jax.vmap(body)(w), mesh=mesh,
-                          in_specs=(P("cluster"),),
-                          out_specs=P("cluster"))
+        # check_vma off: a Pallas kernel in the lane body does not declare
+        # how its outputs vary over the mesh axis (each lane is local)
+        inner = jax.shard_map(lambda w: jax.vmap(body)(w), mesh=mesh,
+                              in_specs=(P("cluster"),),
+                              out_specs=P("cluster"), check_vma=False)
     else:
         pad = 0
         inner = jax.vmap(body)
@@ -430,7 +432,7 @@ class ClusterScheduler:
     Execution modes (``execute(mem, mode=...)``):
 
     * ``"shard_map"`` — stacked windows sharded over a 1-D "cluster" device
-      mesh (through ``distributed.compat``); each device runs its lanes'
+      mesh (``jax.shard_map``); each device runs its lanes'
       shared program. Requires uniform + traceable sub-streams, >= 2 devices.
     * ``"vmap"``      — the same stacked body batched on one device: the
       lanes execute as ONE fused computation (overlapped, no per-stream

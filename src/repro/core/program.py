@@ -36,6 +36,9 @@ from .descriptor import Agu, Descriptor, Opcode
 
 _REDUCE_OPS = {"sum": Opcode.VSUM, "min": Opcode.MIN, "max": Opcode.MAX,
                "argmin": Opcode.ARGMIN, "argmax": Opcode.ARGMAX}
+#: the arg reductions store their index in the fp32 image, which holds
+#: every integer exactly only up to 2**24
+_MAX_EXACT_INDEX = 1 << 24
 
 
 def _align_up(x: int, mult: int) -> int:
@@ -311,6 +314,44 @@ class Program:
         self.emit(dsc.laplace1d(n, x.offset, coef.offset, out.offset))
         return out
 
+    def conv2d(self, img: BufferHandle, ker: BufferHandle,
+               out: Optional[BufferHandle] = None) -> BufferHandle:
+        """2-D valid correlation of an (H, W) plane with (kh, kw) taps as
+        one command -> an (H - kh + 1, W - kw + 1) buffer."""
+        img, ker = self.resolve(img), self.resolve(ker)
+        if len(img.shape) != 2 or len(ker.shape) != 2:
+            raise ValueError(f"conv2d needs 2-D image and taps, got "
+                             f"{img.shape} and {ker.shape}")
+        (h, w), (kh, kw) = img.shape, ker.shape
+        oh, ow = h - kh + 1, w - kw + 1
+        if oh < 1 or ow < 1:
+            raise ValueError(f"taps {ker.shape} larger than image {img.shape}")
+        out = self._out_like(img, out, shape=(oh, ow))
+        self.emit(dsc.conv2d(oh, ow, kh, kw, img.offset, ker.offset,
+                             out.offset, w))
+        return out
+
+    def laplace2d(self, x: BufferHandle, coef: BufferHandle,
+                  out: Optional[BufferHandle] = None) -> BufferHandle:
+        """2-D star stencil on the interior of an (H, W) plane, decomposed
+        per dimension (§III-B3): a 3-tap pass along the rows, one down the
+        columns, and their sum -> an (H - 2, W - 2) buffer. With
+        ``coef = (1, -2, 1)`` this is the discrete Laplace operator."""
+        x, coef = self.resolve(x), self.resolve(coef)
+        if len(x.shape) != 2 or coef.size != 3:
+            raise ValueError(f"laplace2d needs a 2-D plane and 3 "
+                             f"coefficients, got {x.shape} and {coef.size}")
+        h, w = x.shape
+        if h < 3 or w < 3:
+            raise ValueError(f"plane too small: {x.shape}")
+        out = self._out_like(x, out, shape=(h - 2, w - 2))
+        down = self.buffer((h - 2, w - 2))
+        self.emit(dsc.stencil_pass(h - 2, w - 2, 3, 1, x.offset + w,
+                                   coef.offset, out.offset, w))
+        self.emit(dsc.stencil_pass(h - 2, w - 2, 3, 0, x.offset + 1,
+                                   coef.offset, down.offset, w))
+        return self.add(out, down, out=out)
+
     # -- reductions ----------------------------------------------------
     def reduce(self, op: str, x: BufferHandle,
                out: Optional[BufferHandle] = None,
@@ -328,6 +369,10 @@ class Program:
             raise ValueError(f"op must be one of {sorted(_REDUCE_OPS)}, "
                              f"got {op!r}")
         x = self.resolve(x)
+        if op in ("argmin", "argmax") and x.size > _MAX_EXACT_INDEX:
+            raise ValueError(
+                f"{op} over {x.size} elements: the index is written back "
+                f"as fp32, exact only up to {_MAX_EXACT_INDEX} elements")
         if out is None:
             out = self.buffer((1,), name=name)
         else:

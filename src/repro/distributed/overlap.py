@@ -14,7 +14,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .compat import pcast_varying
 
 
 def ring_allgather_matmul(x: jnp.ndarray, w: jnp.ndarray,
@@ -25,8 +24,8 @@ def ring_allgather_matmul(x: jnp.ndarray, w: jnp.ndarray,
     n = jax.lax.psum(1, axis_name)
     idx = jax.lax.axis_index(axis_name)
     s_local = x.shape[0]
-    out = pcast_varying(jnp.zeros((n * s_local, w.shape[1]), jnp.float32),
-                        axis_name)
+    out = jax.lax.pcast(jnp.zeros((n * s_local, w.shape[1]), jnp.float32),
+                        axis_name, to="varying")
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     def body(i, carry):
@@ -54,8 +53,8 @@ def ring_matmul_reducescatter(x: jnp.ndarray, w: jnp.ndarray,
     idx = jax.lax.axis_index(axis_name)
     s_local = x.shape[0] // n
     perm = [(i, (i + 1) % n) for i in range(n)]
-    acc = pcast_varying(jnp.zeros((s_local, w.shape[1]), jnp.float32),
-                        axis_name)
+    acc = jax.lax.pcast(jnp.zeros((s_local, w.shape[1]), jnp.float32),
+                        axis_name, to="varying")
 
     def body(i, acc):
         # shift the partial sum in from the previous device (zeros at i=0),

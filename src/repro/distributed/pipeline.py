@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import pcast_varying, shard_map
 
 
 def gpipe(body: Callable, axis_name: str):
@@ -36,7 +35,7 @@ def gpipe(body: Callable, axis_name: str):
         perm = [(i, i + 1) for i in range(n_stage - 1)]
 
         total = n_micro + n_stage - 1
-        ys = pcast_varying(jnp.zeros_like(xs), axis_name)
+        ys = jax.lax.pcast(jnp.zeros_like(xs), axis_name, to="varying")
 
         def step(t, carry):
             cur, ys = carry                      # cur: activation entering
@@ -58,7 +57,8 @@ def gpipe(body: Callable, axis_name: str):
             cur = jax.lax.ppermute(y, axis_name, perm) if n_stage > 1 else y
             return cur, ys
 
-        cur = pcast_varying(jnp.zeros(mb_shape, xs.dtype), axis_name)
+        cur = jax.lax.pcast(jnp.zeros(mb_shape, xs.dtype), axis_name,
+                            to="varying")
         cur, ys = jax.lax.fori_loop(0, total, step, (cur, ys))
         # results live on the last stage only; broadcast to all stages
         return jax.lax.psum(ys, axis_name)
@@ -70,5 +70,5 @@ def pipelined_apply(mesh: Mesh, body: Callable, stage_axis: str,
                     params_specs, x_spec, y_spec):
     """Wrap ``gpipe`` in shard_map over ``stage_axis`` of ``mesh``."""
     run = gpipe(body, stage_axis)
-    return shard_map(run, mesh=mesh, in_specs=(params_specs, x_spec),
-                     out_specs=y_spec, check_vma=False)
+    return jax.shard_map(run, mesh=mesh, in_specs=(params_specs, x_spec),
+                         out_specs=y_spec, check_vma=False)

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import compat
 
 _OPS1 = {"relu", "thresh", "copy", "set"}
 _OPS2 = {"axpy", "add", "sub", "mul", "mask"}
@@ -78,7 +77,7 @@ def elementwise_pallas(op: str, x: jnp.ndarray, y: jnp.ndarray | None = None,
         in_specs=in_specs,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*args)
@@ -135,7 +134,7 @@ def elementwise_chain_pallas(stages, x: jnp.ndarray,
         in_specs=[spec] * len(args),
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 ("arbitrary",) if double_buffer else ("parallel",))),
         interpret=interpret,
@@ -176,7 +175,7 @@ def adamw_pallas(p, g, m, v, step, *, lr, b1=0.9, b2=0.999, eps=1e-8,
         out_shape=(jax.ShapeDtypeStruct((rows, n), p.dtype),
                    jax.ShapeDtypeStruct((rows, n), jnp.float32),
                    jax.ShapeDtypeStruct((rows, n), jnp.float32)),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(p, g, m.astype(jnp.float32), v.astype(jnp.float32), bc)

@@ -37,7 +37,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import compat
 
 
 #: Epilogue stage kinds that carry a streamed array operand (in order).
@@ -88,6 +87,16 @@ def apply_epilogue(acc, stages, operands):
     return acc
 
 
+def _dot(a, b):
+    """fp32 accumulate; fp32 operands multiply at full fp32 precision (the
+    MXU's multi-pass mode), like the NTX FMA's fp32 product. Without the
+    explicit precision the MXU may round fp32 operands to bf16."""
+    precision = (jax.lax.Precision.HIGHEST
+                 if jnp.float32 in (a.dtype, b.dtype) else None)
+    return jnp.dot(a, b, precision=precision,
+                   preferred_element_type=jnp.float32)
+
+
 def _gemm_kernel(a_ref, b_ref, *rest, nk: int, out_dtype, stages=(),
                  n_ep: int = 0):
     ep_refs = rest[:n_ep]
@@ -100,7 +109,7 @@ def _gemm_kernel(a_ref, b_ref, *rest, nk: int, out_dtype, stages=(),
 
     a = a_ref[...]
     b = b_ref[...]
-    acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
+    acc_ref[...] += _dot(a, b)
 
     @pl.when(k == nk - 1)
     def _store():                      # descriptor store_level: one rounding
@@ -120,7 +129,7 @@ def _gemm_kernel_kahan(a_ref, b_ref, *rest, nk: int, out_dtype, stages=(),
         acc_ref[...] = jnp.zeros_like(acc_ref)
         comp_ref[...] = jnp.zeros_like(comp_ref)
 
-    x = jnp.dot(a_ref[...], b_ref[...], preferred_element_type=jnp.float32)
+    x = _dot(a_ref[...], b_ref[...])
     acc = acc_ref[...]
     t = acc + x
     comp_ref[...] += jnp.where(jnp.abs(acc) >= jnp.abs(x),
@@ -188,7 +197,7 @@ def gemm_pallas(a: jnp.ndarray, b: jnp.ndarray, *,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),  # AGU2
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=scratch,
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, *ep_args)

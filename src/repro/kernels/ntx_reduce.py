@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import compat
 from .ntx_elementwise import _apply_op, _OPS2
 
 _INIT = {"sum": 0.0, "min": float("inf"), "max": float("-inf"),
@@ -144,7 +143,7 @@ def chain_reduce_pallas(stages, red: str, x: jnp.ndarray, ys: tuple = (),
                    jax.ShapeDtypeStruct((rows, 1), jnp.float32)),
         scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
                         pltpu.VMEM((rows, 1), jnp.int32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*args)
@@ -168,7 +167,7 @@ def reduce_pallas(op: str, x: jnp.ndarray, block: int = 512,
         out_shape=jax.ShapeDtypeStruct((rows, 1), out_dtype),
         scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
                         pltpu.VMEM((rows, 1), jnp.int32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x)

@@ -20,13 +20,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jax._src.pallas.mosaic.error_handling import MosaicError
+from jax._src.pallas.mosaic.lowering import LoweringException
+
 from . import ref
 from .ntx_gemm import EPILOGUE_ARRAY_KINDS, gemm_pallas
 from .ntx_elementwise import (_OPS2, adamw_pallas, elementwise_chain_pallas,
                               elementwise_pallas)
 from .ntx_reduce import chain_reduce_pallas, reduce_pallas
 from .ntx_conv import conv2d_pallas
-from .ntx_stencil import stencil1d_pallas
 from .flash_attention import flash_attention_pallas
 from .ssd_scan import ssd_scan_pallas
 
@@ -81,7 +83,17 @@ def _pad_to(x: jnp.ndarray, axis: int, mult: int, value=0.0):
 # measure-and-pick); the scheduler model is the default and the fallback.
 # ----------------------------------------------------------------------
 _BLOCK_CACHE: dict = {}
-_BLOCK_CACHE_STATS = {"hits": 0, "misses": 0, "measured": 0}
+_BLOCK_CACHE_STATS = {"hits": 0, "misses": 0, "measured": 0, "refused": 0}
+
+#: what the chip's compilers raise for a candidate they refuse: Pallas's
+#: Mosaic lowering (NotImplementedError for a primitive it cannot lower,
+#: ValueError for a block off the 8 x 128 tiling, LoweringException with
+#: verbose Pallas errors on, MosaicError from Mosaic's own compile) and
+#: XLA's compile step (JaxRuntimeError, e.g. a kernel that asks for more
+#: VMEM than there is). Caught only around lowering and compiling
+#: (``compile_or_refusal``), never around a run.
+LOWERING_ERRORS = (NotImplementedError, ValueError, LoweringException,
+                   MosaicError, jax.errors.JaxRuntimeError)
 
 _AUTOTUNE_MODES = ("model", "measure")
 _AUTOTUNE_OVERRIDE: str | None = None
@@ -139,29 +151,48 @@ def _candidate_blocks(m: int, n: int, k: int, base) -> list:
     return out
 
 
+def compile_or_refusal(fn, *args):
+    """``(compiled fn, None)`` for ``args``, or ``(None, error)`` when the
+    compiler refuses it (``LOWERING_ERRORS`` from lowering or compiling).
+    Tracing runs outside the catch, so an error in the Python code itself
+    propagates, as does anything the compiled function raises when run."""
+    traced = jax.jit(fn).trace(*args)
+    try:
+        return traced.lower().compile(), None
+    except LOWERING_ERRORS as e:
+        return None, e
+
+
 def _measure_pick(m: int, n: int, k: int, base) -> tuple[int, int, int]:
     """Race the candidate triples on a representative GEMM and keep the
-    fastest (first sight of a shape only — the result is cached)."""
+    fastest (first sight of a shape only — the result is cached). A
+    candidate the compiler refuses is counted (``refused`` in
+    ``block_cache_stats``); if every candidate is refused, the last
+    refusal is raised."""
     a = jnp.ones((m, k), jnp.float32)
     b = jnp.ones((k, n), jnp.float32)
-    best, best_t = base, float("inf")
+    best, best_t, last_err = base, float("inf"), None
     for cand in _candidate_blocks(m, n, k, base):
         bm, bn, bk = cand
         a2, _ = _pad_to(a, 0, bm)
         a2, _ = _pad_to(a2, 1, bk)
         b2, _ = _pad_to(b, 0, bk)
         b2, _ = _pad_to(b2, 1, bn)
-        try:
-            run = lambda: gemm_pallas(a2, b2, block_m=bm, block_n=bn,
-                                      block_k=bk, interpret=_interp())
-            jax.block_until_ready(run())       # compile + warm
-            t0 = time.perf_counter()
-            jax.block_until_ready(run())
-            dt = time.perf_counter() - t0
-        except Exception:
-            continue                           # candidate does not lower
+        run, err = compile_or_refusal(
+            functools.partial(gemm_pallas, block_m=bm, block_n=bn,
+                              block_k=bk, interpret=_interp()), a2, b2)
+        if err is not None:
+            _BLOCK_CACHE_STATS["refused"] += 1
+            last_err = err
+            continue
+        jax.block_until_ready(run(a2, b2))     # warm
+        t0 = time.perf_counter()
+        jax.block_until_ready(run(a2, b2))
+        dt = time.perf_counter() - t0
         if dt < best_t:
             best, best_t = cand, dt
+    if best_t == float("inf"):
+        raise last_err
     return best
 
 
@@ -452,27 +483,18 @@ def reduce(op: str, x: jnp.ndarray) -> jnp.ndarray:
 
 
 # ----------------------------------------------------------------------
-# Convolution (host tiles strips like the RISC-V does in the paper)
+# Convolution (the Pallas grid walks output tiles, as the RISC-V host does)
 # ----------------------------------------------------------------------
-def conv2d(img: jnp.ndarray, ker: jnp.ndarray,
-           strip_rows: int = 256) -> jnp.ndarray:
+def conv2d(img: jnp.ndarray, ker: jnp.ndarray) -> jnp.ndarray:
     if not _pallas():
         return ref.conv2d(img, ker)
-    h, w = img.shape
-    kh, kw = ker.shape
-    oh = h - kh + 1
-    outs = []
-    r = 0
-    while r < oh:
-        rows = min(strip_rows, oh - r)
-        strip = jax.lax.dynamic_slice(img, (r, 0), (rows + kh - 1, w))
-        outs.append(conv2d_pallas(strip, ker, interpret=_interp()))
-        r += rows
-    return jnp.concatenate(outs, 0)
+    return conv2d_pallas(img, ker, interpret=_interp())
 
 
 # ----------------------------------------------------------------------
-# Stencils
+# Stencils (paper §III-B3: a star stencil decomposes into 1-D passes per
+# dimension; a pass along the last axis is a valid correlation with a
+# (1, k) tap row, so it runs on the tiled conv kernel)
 # ----------------------------------------------------------------------
 def stencil_axis(x: jnp.ndarray, coeffs: jnp.ndarray, axis: int) -> jnp.ndarray:
     if not _pallas():
@@ -480,9 +502,9 @@ def stencil_axis(x: jnp.ndarray, coeffs: jnp.ndarray, axis: int) -> jnp.ndarray:
     x2 = jnp.moveaxis(x, axis, -1)
     lead = x2.shape[:-1]
     rows = int(np.prod(lead)) if lead else 1
-    out = stencil1d_pallas(x2.reshape(rows, x2.shape[-1]),
-                           jnp.asarray(coeffs, jnp.float32),
-                           interpret=_interp())
+    out = conv2d_pallas(x2.reshape(rows, x2.shape[-1]),
+                        jnp.asarray(coeffs, jnp.float32).reshape(1, -1),
+                        interpret=_interp())
     out = out.reshape(*lead, out.shape[-1])
     return jnp.moveaxis(out, -1, axis)
 

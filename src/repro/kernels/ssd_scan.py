@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import compat
 
 
 def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, s_ref, *,
@@ -33,34 +32,42 @@ def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, s_ref, *,
         s_ref[...] = jnp.zeros_like(s_ref)
 
     x = x_ref[0].astype(jnp.float32)              # (L, dh)
-    dt = dt_ref[0].astype(jnp.float32)            # (L,)
+    dt = dt_ref[0].astype(jnp.float32)            # (L, 1) column
     B = b_ref[0].astype(jnp.float32)              # (L, n)
     C = c_ref[0].astype(jnp.float32)              # (L, n)
     A = a_ref[h]                                  # scalar decay rate (<0)
 
-    la = jnp.cumsum(dt * A)                       # (L,) log-decay, inclusive
+    # inclusive log-decay prefix sums, as a column and as a row, from
+    # masked sums over the (L, L) broadcast of dt*A: Mosaic keeps 1-D
+    # vectors (and cumsum) out of the kernel
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tri = row >= col
+    da = jnp.broadcast_to(dt * A, (chunk, chunk))           # da[i, j] = dA[i]
+    la_col = jnp.sum(jnp.where(tri, da.T, 0.0), axis=1,
+                     keepdims=True)                         # (L, 1)
+    la_row = jnp.sum(jnp.where(row <= col, da, 0.0), axis=0,
+                     keepdims=True)                         # (1, L)
     # intra-chunk quadratic part: masked decay-weighted (C.B^T)
-    dec = jnp.exp(la[:, None] - la[None, :])
-    tri = jax.lax.broadcasted_iota(jnp.int32, dec.shape, 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, dec.shape, 1)
+    dec = jnp.exp(la_col - la_row)
     cb = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
     w = jnp.where(tri, cb * dec, 0.0)             # (L, L)
-    xdt = x * dt[:, None]                         # (L, dh)
+    xdt = x * dt                                  # (L, dh)
     y = jax.lax.dot_general(w, xdt, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
 
     # inter-chunk: contribution of the carried state
     s = s_ref[...]                                # (n, dh)
-    y = y + jnp.exp(la)[:, None] * jax.lax.dot_general(
+    y = y + jnp.exp(la_col) * jax.lax.dot_general(
         C, s, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
     # state update (the wide-accumulator MAC): S <- e^{la_L} S + B^T W X
-    la_last = la[chunk - 1]
-    wS = jnp.exp(la_last - la) * dt               # (L,)
+    la_last = la_col[chunk - 1:chunk, :]          # (1, 1)
+    wS = jnp.exp(la_last - la_col) * dt           # (L, 1)
     s_ref[...] = jnp.exp(la_last) * s + jax.lax.dot_general(
-        B * wS[:, None], x,
-        (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        (B * wS).T, x, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
     y_ref[0] = y.astype(y_ref.dtype)
 
@@ -80,14 +87,14 @@ def ssd_scan_pallas(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),                   # A
             pl.BlockSpec((1, chunk, dh), lambda h, c: (h, c, 0)),    # x
-            pl.BlockSpec((1, chunk), lambda h, c: (h, c)),           # dt
+            pl.BlockSpec((1, chunk, 1), lambda h, c: (h, c, 0)),     # dt
             pl.BlockSpec((1, chunk, n), lambda h, c: (h, c, 0)),     # B
             pl.BlockSpec((1, chunk, n), lambda h, c: (h, c, 0)),     # C
         ],
         out_specs=pl.BlockSpec((1, chunk, dh), lambda h, c: (h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, l, dh), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, dh), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(A.astype(jnp.float32), x, dt, B, C)
+    )(A.astype(jnp.float32), x, dt[..., None], B, C)

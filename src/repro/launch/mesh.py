@@ -7,18 +7,10 @@ lines 1-2); real deployments get the same topology from the TPU runtime.
 """
 from __future__ import annotations
 
-import jax
+from repro.distributed.mesh import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh_for(n_devices: int, model_parallel: int = 1):
-    """Elastic helper: best (data, model) mesh for an arbitrary device
-    count (used by examples/tests on 1..8 host devices)."""
-    assert n_devices % model_parallel == 0
-    return jax.make_mesh((n_devices // model_parallel, model_parallel),
-                         ("data", "model"))
+    return make_mesh(shape, axes)

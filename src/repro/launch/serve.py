@@ -1,7 +1,10 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Loads params from a checkpoint directory if given (CheckpointManager
-layout), otherwise serves random-init weights of the reduced config.
+Serves the published widths of ``--arch`` (``--reduced`` for the CPU smoke
+config, ``--set key=value`` to override any ArchConfig field, e.g.
+``--set n_layers=16`` to cut depth). Loads params from a checkpoint
+directory if given (CheckpointManager layout) — a restore that fails is
+an error — otherwise serves random-init weights made from ``--seed``.
 """
 import argparse
 import sys
@@ -9,45 +12,51 @@ import sys
 import numpy as np
 
 
-def main():
+def _parse(argv=None) -> argparse.Namespace:
+    from repro.launch.common import add_config_args
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="llama3-8b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    add_config_args(ap, default_arch="llama3-8b")
     ap.add_argument("--ckpt", default=None)
+    ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--new-tokens", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.0)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
-    from repro import configs
+
+def load_server(args: argparse.Namespace):
+    """(cfg, Server) for parsed launcher flags: the selected config, its
+    params (restored from ``--ckpt`` or initialised from ``--seed``) and a
+    cache sized for ``--prompt-len`` + ``--new-tokens``."""
+    from repro.launch.common import config_from_args
     from repro.models import Model
     from repro.runtime import Server, ServeConfig
 
-    cfg = (configs.get_reduced(args.arch) if args.reduced
-           else configs.get(args.arch))
+    cfg = config_from_args(args)
     if cfg.encoder_decoder or cfg.n_patches:
-        print(f"{args.arch} needs frontend inputs — see "
-              "examples/multimodal_stub.py")
-        return 1
-    model = Model(cfg)
-    params = model.init(0)
+        raise SystemExit(f"{args.arch} needs frontend inputs — see "
+                         "examples/multimodal_stub.py")
+    params = Model(cfg).init(args.seed)
     if args.ckpt:
         from repro.checkpoint import CheckpointManager
-        mgr = CheckpointManager(args.ckpt)
-        state_like = {"params": params}
-        try:
-            restored, step = mgr.restore(state_like)
-            params = restored["params"]
-            print(f"restored params from step {step}")
-        except Exception as e:  # pragma: no cover
-            print(f"checkpoint restore failed ({e}); serving random init")
-
+        restored, step = CheckpointManager(args.ckpt).restore(
+            {"params": params})
+        params = restored["params"]
+        print(f"restored params from step {step}")
     srv = Server(cfg, params, ServeConfig(
         max_seq=args.prompt_len + args.new_tokens + 8,
         max_new_tokens=args.new_tokens, eos_token=-1,
-        temperature=args.temperature))
-    rng = np.random.default_rng(0)
+        temperature=args.temperature, seed=args.seed))
+    return cfg, srv
+
+
+def main(argv=None):
+    from repro.launch.common import enable_compile_cache
+    args = _parse(argv)
+    enable_compile_cache()
+    cfg, srv = load_server(args)
+    rng = np.random.default_rng(args.seed)
     prompts = [rng.integers(0, cfg.vocab, args.prompt_len)
                for _ in range(args.batch)]
     out = srv.generate(prompts)

@@ -11,11 +11,10 @@ import os
 import sys
 
 
-def _parse():
+def _parse(argv=None):
+    from repro.launch.common import add_config_args
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="llama3-8b")
-    ap.add_argument("--reduced", action="store_true",
-                    help="use the reduced smoke config (CPU-friendly)")
+    add_config_args(ap, default_arch="llama3-8b")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--global-batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -26,42 +25,27 @@ def _parse():
     ap.add_argument("--devices", type=int, default=0,
                     help="emulate N host devices (CPU only)")
     ap.add_argument("--mesh", default=None, help="DxM, e.g. 4x2")
-    ap.add_argument("--set", nargs="*", default=[],
-                    help="ArchConfig overrides key=value")
-    return ap.parse_args()
+    return ap.parse_args(argv)
 
 
-def main():
-    args = _parse()
+def main(argv=None):
+    args = _parse(argv)
     if args.devices:
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                                    f" --xla_force_host_platform_device_count"
                                    f"={args.devices}")
-    import jax
-    from repro import configs
+    from repro.launch.common import config_from_args, enable_compile_cache
     from repro.optim import AdamWConfig
     from repro.runtime import TrainConfig, Trainer
 
-    cfg = (configs.get_reduced(args.arch) if args.reduced
-           else configs.get(args.arch))
-    overrides = {}
-    for kv in args.set:
-        k, v = kv.split("=", 1)
-        try:
-            v = int(v)
-        except ValueError:
-            try:
-                v = float(v)
-            except ValueError:
-                v = {"true": True, "false": False}.get(v.lower(), v)
-        overrides[k] = v
-    if overrides:
-        cfg = cfg.scaled(**overrides)
+    enable_compile_cache()
+    cfg = config_from_args(args)
 
     mesh = None
     if args.mesh:
         d, m = map(int, args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        from repro.distributed.mesh import make_mesh
+        mesh = make_mesh((d, m), ("data", "model"))
         from repro.models.common import set_activation_sharding
         set_activation_sharding(mesh, ("data",), "model")
 

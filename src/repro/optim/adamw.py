@@ -46,8 +46,10 @@ def init_opt_state(params: Any) -> dict:
     """master fp32 + first/second moments (+ step counter).
 
     zeros_like (not zeros) so moments inherit the params' shardings when
-    initialised from mesh-distributed parameters."""
-    master = jax.tree.map(lambda p: p.astype(jnp.float32), params)
+    initialised from mesh-distributed parameters. ``master`` is always a
+    copy, even of fp32 params, so a train step may donate both trees."""
+    master = jax.tree.map(lambda p: jnp.array(p, jnp.float32, copy=True),
+                          params)
     zeros = jax.tree.map(lambda p: jnp.zeros_like(p, dtype=jnp.float32),
                          params)
     # the step counter is a host scalar (uncommitted) so the state tree

@@ -249,9 +249,12 @@ class Server:
             self.params, batch, cache_len=scfg.max_seq)
         prefill_s = time.perf_counter() - t0
 
+        # the unembedding is padded past the vocabulary (see
+        # ArchConfig.padded_vocab); padded ids are never sampled
+        vocab = self.cfg.vocab
         out = [[] for _ in range(b)]
         done = np.zeros(b, bool)
-        cur = self._sample(logits, rng, prefill=True)
+        cur = self._sample(logits[:, :vocab], rng, prefill=True)
         fill = jnp.int32(fill)
         t1 = time.perf_counter()
         steps = 0
@@ -267,7 +270,7 @@ class Server:
                                          jnp.asarray(cur[:, None], jnp.int32),
                                          cache, fill)
             fill = fill + 1
-            cur = self._sample(logits[:, -1], rng)
+            cur = self._sample(logits[:, -1, :vocab], rng)
             steps += 1
         decode_s = time.perf_counter() - t1
         return {"completions": out,

@@ -108,17 +108,10 @@ def build_step_fn(cfg: ArchConfig, opt_cfg: AdamWConfig,
     return step_fn
 
 
-def make_train_step(cfg: ArchConfig, opt_cfg: AdamWConfig,
-                    mesh: Optional[Mesh] = None):
-    """Build the jitted train step. With a mesh, in/out shardings are the
-    production DP/TP/EP layout; without, single-device jit."""
-    model = Model(cfg)
-    step_fn = build_step_fn(cfg, opt_cfg)
-
-    if mesh is None:
-        return jax.jit(step_fn)
-
-    params_shape = jax.eval_shape(lambda: model.init(0))
+def state_shardings(cfg: ArchConfig, mesh: Mesh):
+    """(param shardings, optimizer-state shardings) of the production
+    DP/TP/EP layout on ``mesh`` (ZeRO-1 optimizer state)."""
+    params_shape = jax.eval_shape(lambda: Model(cfg).init(0))
     pspecs = shd.param_shardings(mesh, params_shape)
     ospecs = {"master": shd.opt_state_specs(mesh, params_shape),
               "m": shd.opt_state_specs(mesh, params_shape),
@@ -127,10 +120,27 @@ def make_train_step(cfg: ArchConfig, opt_cfg: AdamWConfig,
     ospecs = jax.tree.map(
         lambda s: NamedSharding(mesh, s) if isinstance(s, P) else s, ospecs,
         is_leaf=lambda s: isinstance(s, P))
+    return pspecs, ospecs
+
+
+def make_train_step(cfg: ArchConfig, opt_cfg: AdamWConfig,
+                    mesh: Optional[Mesh] = None):
+    """Build the jitted train step. With a mesh, in/out shardings are the
+    production DP/TP/EP layout; without, single-device jit. The step takes
+    the params and optimizer state as donations, so the new state reuses
+    their buffers: the caller must not read the old ones again."""
+    step_fn = build_step_fn(cfg, opt_cfg)
+    donate_argnums = (0, 1)
+
+    if mesh is None:
+        return jax.jit(step_fn, donate_argnums=donate_argnums)
+
+    pspecs, ospecs = state_shardings(cfg, mesh)
     return jax.jit(step_fn,
                    in_shardings=(pspecs, ospecs, None),
                    out_shardings=(pspecs, ospecs,
-                                  NamedSharding(mesh, P()), None))
+                                  NamedSharding(mesh, P()), None),
+                   donate_argnums=donate_argnums)
 
 
 def plan_update_multistream(params, n_clusters: Optional[int] = None,

@@ -31,23 +31,23 @@ def test_compressed_psum_and_ring_collectives():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.distributed import collectives, overlap
-        from repro.distributed.compat import shard_map
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.distributed.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
         x = rng.standard_normal((8, 1000)).astype(np.float32)
-        f = shard_map(lambda v: collectives.compressed_psum_mean(v[0], "data")[None],
+        f = jax.shard_map(lambda v: collectives.compressed_psum_mean(v[0], "data")[None],
                       mesh=mesh, in_specs=P("data", None),
                       out_specs=P("data", None))
         got = np.asarray(jax.jit(f)(x))
         rel = float(np.abs(got - x.mean(0)).max() / np.abs(x.mean(0)).max())
         xs = rng.standard_normal((64, 32)).astype(np.float32)
         w = rng.standard_normal((32, 48)).astype(np.float32)
-        f2 = shard_map(lambda xl, wl: overlap.ring_allgather_matmul(xl, wl, "data"),
+        f2 = jax.shard_map(lambda xl, wl: overlap.ring_allgather_matmul(xl, wl, "data"),
                        mesh=mesh, in_specs=(P("data", None), P(None, "data")),
                        out_specs=P(None, "data"))
         ag_ok = bool(np.allclose(jax.jit(f2)(xs, w), xs @ w, atol=1e-4))
         w2 = rng.standard_normal((32, 16)).astype(np.float32)
-        f3 = shard_map(lambda xl, wl: overlap.ring_matmul_reducescatter(xl, wl, "data"),
+        f3 = jax.shard_map(lambda xl, wl: overlap.ring_matmul_reducescatter(xl, wl, "data"),
                        mesh=mesh, in_specs=(P(None, "data"), P("data", None)),
                        out_specs=P("data", None))
         rs_ok = bool(np.allclose(jax.jit(f3)(xs, w2), xs @ w2, atol=1e-3))
@@ -63,7 +63,8 @@ def test_pipeline_parallelism():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.distributed import pipeline
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.distributed.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
         params = rng.standard_normal((8, 16)).astype(np.float32)
         xs = rng.standard_normal((12, 4, 16)).astype(np.float32)
@@ -88,6 +89,7 @@ def test_sharded_train_step_matches_single_device():
         from repro import configs
         from repro.models import Model
         from repro.optim import AdamWConfig, init_opt_state
+        from repro.distributed.mesh import make_mesh
         from repro.runtime.train import make_train_step
         cfg = configs.get_reduced("llama3-8b").scaled(
             compute_dtype="float32", param_dtype="float32")
@@ -99,8 +101,9 @@ def test_sharded_train_step_matches_single_device():
                  "labels": jnp.asarray(rng.integers(0, cfg.vocab, (8, 32)), jnp.int32)}
         opt_cfg = AdamWConfig(lr=1e-3)
         single = jax.jit(make_train_step(cfg, opt_cfg, mesh=None))
-        p1, o1, l1, _ = single(params, opt, batch)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        copy = lambda t: jax.tree.map(jnp.copy, t)   # the step donates
+        p1, o1, l1, _ = single(copy(params), copy(opt), batch)
+        mesh = make_mesh((2, 4), ("data", "model"))
         from repro.models.common import set_activation_sharding
         set_activation_sharding(mesh, ("data",), "model")
         with mesh:

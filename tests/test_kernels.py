@@ -80,7 +80,19 @@ def test_conv2d_sweep(ksize):
     img = _arr((64, 96))
     ker = _arr((ksize, ksize))
     with ops.backend("pallas_interpret"):
-        got = ops.conv2d(img, ker, strip_rows=17)
+        got = ops.conv2d(img, ker)
+    want = ref.conv2d(img, ker)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("ksize", [1, 3, 5])
+def test_conv2d_tiles_cover_plane(ksize):
+    """Several output tiles in both directions: each tile's halo comes from
+    the blocks below, to the right and at the corner."""
+    from repro.kernels.ntx_conv import conv2d_pallas
+    img = _arr((300, 4200))                 # 3 x 3 tiles of 128 x 2048
+    ker = _arr((ksize, ksize))
+    got = conv2d_pallas(img, ker, interpret=True)
     want = ref.conv2d(img, ker)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 

@@ -34,10 +34,11 @@ def test_ctx_parallel_loss_parity():
         import jax, jax.numpy as jnp, numpy as np
         from repro import configs
         from repro.models import Model
+        from repro.distributed.mesh import make_mesh
         from repro.models.common import set_activation_sharding
         cfg = configs.get_reduced("llama3-8b").scaled(
             compute_dtype="float32", param_dtype="float32")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         set_activation_sharding(mesh, ("data",), "model")
         rng = np.random.default_rng(0)
         batch = {"tokens": jnp.asarray(rng.integers(0, cfg.vocab, (4, 32)),
@@ -87,6 +88,7 @@ def test_elastic_mesh_rescale():
         from repro import configs
         from repro.checkpoint import CheckpointManager, reshard_checkpoint
         from repro.distributed import sharding as shd
+        from repro.distributed.mesh import make_mesh
         from repro.models import Model
         from repro.optim import AdamWConfig, init_opt_state
         from repro.runtime.train import build_step_fn
@@ -101,7 +103,7 @@ def test_elastic_mesh_rescale():
                                        jnp.int32)}
         step = jax.jit(build_step_fn(cfg, AdamWConfig(lr=1e-3)))
 
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = make_mesh((4, 2), ("data", "model"))
         pshape = jax.eval_shape(lambda: model.init(0))
         sh_a = shd.param_shardings(mesh_a, pshape)
         params_a = jax.tree.map(lambda x, s: jax.device_put(x, s),
@@ -114,7 +116,7 @@ def test_elastic_mesh_rescale():
         mgr.save(1, {"params": p1, "opt": o1})
 
         # 'rescale': new mesh shape, restore with the new shardings
-        mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_b = make_mesh((2, 4), ("data", "model"))
         sh_b = shd.param_shardings(mesh_b, pshape)
         params_b = jax.tree.map(lambda x, s: jax.device_put(x, s),
                                 params, sh_b)

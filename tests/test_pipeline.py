@@ -430,7 +430,7 @@ def test_autotune_cache_keyed_by_backend_and_mode(monkeypatch):
     assert st2["hits"] == st1["hits"] + 1 and st2["measured"] == 1
     ops.clear_autotune_cache()
     assert ops.block_cache_stats() == {"hits": 0, "misses": 0,
-                                       "measured": 0}
+                                       "measured": 0, "refused": 0}
 
 
 def test_autotune_cache_keyed_by_dtype_bytes():
