@@ -217,7 +217,6 @@ def phase_serve(chk, seed):
     import jax.numpy as jnp
     from repro import configs
     from repro.launch import serve as launcher
-    from repro.runtime.serve import sampler_stats
 
     args = launcher._parse(SERVE_FLAGS + ["--seed", str(seed)])
     batch, prompt_len, new_tokens = (args.batch, args.prompt_len,
@@ -241,9 +240,7 @@ def phase_serve(chk, seed):
                 and all(len(c) == new_tokens for c in comps)
                 and all(0 <= t < cfg.vocab for c in comps for t in c))
     log(phase=chk.phase,
-        samplers={k: (v["policy"], (v["scheduler"] or {}).get("mode_used"))
-                  for k, v in sampler_stats().items()},
-        prefill_s_host_clock=out["prefill_s"],
+        time_to_first_tokens_s_host_clock=out["prefill_s"],
         decode_tok_per_s_host_clock=out["decode_tok_per_s"],
         first_completion=comps[0][:8])
     chk("completions_in_vocab", in_vocab,
@@ -285,7 +282,8 @@ def phase_four_chips(chk, seed):
     from repro.models import Model
     from repro.models.common import set_activation_sharding
     from repro.optim import AdamWConfig, init_opt_state
-    from repro.runtime.serve import greedy_argmax_multistream, sampler_stats
+    from repro.runtime.serve import (_ARGMAX_PROGRAMS,
+                                     greedy_argmax_multistream)
     from repro.runtime.train import make_train_step, state_shardings
 
     rng = np.random.default_rng(seed)
@@ -322,7 +320,7 @@ def phase_four_chips(chk, seed):
     # and results land, and that it still equals numpy's argmax.
     logits = jnp.asarray(uniform(rng, (8, 49155)))
     ids = greedy_argmax_multistream(logits)
-    st = sampler_stats()["decode_b8_v49155"]["scheduler"]
+    st = _ARGMAX_PROGRAMS[(8, 49155)][1].stats["scheduler"]
     log(phase=chk.phase, program="serve_sampler_b8", mode_used=st["mode_used"],
         n_devices_used=st.get("n_devices_used"),
         logits_devices=sorted(d.id for d in logits.devices()))

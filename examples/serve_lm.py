@@ -39,19 +39,10 @@ def main():
                for _ in range(args.batch)]
     out = srv.generate(prompts)
     print(f"arch {cfg.name} (reduced) | batch {args.batch} | "
-          f"prefill {out['prefill_s']*1e3:.0f} ms | "
+          f"time to first tokens {out['prefill_s']*1e3:.0f} ms | "
           f"decode {out['decode_tok_per_s']:.1f} tok/s")
     for i, c in enumerate(out["completions"]):
         print(f"  req{i}: {c[:12]}{'...' if len(c) > 12 else ''}")
-
-    # greedy sampling ran as ntx.Program descriptor programs through the
-    # policy-driven Executor — one ARGMAX sub-stream per request
-    from repro.runtime.serve import sampler_stats
-    for shape, st in sampler_stats().items():
-        sched = st.get("scheduler") or {}
-        print(f"  sampler {shape}: policy={st['policy']} "
-              f"descs={st['n_descriptors']} "
-              f"mode={sched.get('mode_used')}")
 
 
 if __name__ == "__main__":

@@ -55,6 +55,7 @@ from .cluster import NtxClusterSpec, PAPER_CLUSTER
 from .descriptor import Descriptor
 from .memory import NtxMemSpec
 from .program import Program, ProgramResult
+from .spans import span
 
 POLICIES = ("auto", "serial", "fused", "multistream", "pipeline", "tiled")
 TRANSPORTS = ("auto", "vmap", "shard_map", "interleave", "serial",
@@ -340,12 +341,13 @@ class Executor:
 
         The raw-descriptor compatibility layer — new code should build a
         :class:`Program` and call :meth:`run`."""
-        descs = list(descs)
-        mem = jnp.asarray(mem, jnp.float32)
-        chosen, gains = self._resolve(descs, policy, mem)
-        runner, source = self._build_runner(descs, chosen)
-        with self._env():
-            out = runner(mem)
+        with span("ntx.run"):
+            descs = list(descs)
+            mem = jnp.asarray(mem, jnp.float32)
+            chosen, gains = self._resolve(descs, policy, mem)
+            runner, source = self._build_runner(descs, chosen)
+            with span("ntx.execute"), self._env():
+                out = runner(mem)
         self.stats = {"policy": chosen, "gains": gains,
                       "n_descriptors": len(descs),
                       "scheduler": getattr(source, "stats", None)}
@@ -359,35 +361,46 @@ class Executor:
         :meth:`Program.pack`); ``policy`` overrides the executor's policy
         for this call (e.g. ``policy="pipeline"``). Returns a
         :class:`ProgramResult` — index it with the program's handles.
+
+        Spans: ``ntx.run`` around ``ntx.pack``, ``ntx.plan`` (only when
+        the program's plan is not cached, so its count is the misses),
+        ``ntx.execute`` and ``ntx.unpack``.
         """
-        descs = program.descriptors
-        cache = getattr(program, "_plan_cache", None)
-        if cache is None:
-            cache = {}
-            program._plan_cache = cache
-        # cache the resolved policy AND its runner per program version, so
-        # a steady-state loop neither re-prices nor re-plans the program.
-        # backend/autotune are part of the key: a jitted transport bakes
-        # the kernel backend in at trace time, and measured autotune picks
-        # are only valid for the mode they were raced under
-        key = (program.version, policy or self.policy.policy,
-               self._n_clusters(), self.policy.transport,
-               self.policy.backend, self.policy.autotune, self.policy.spec,
-               self.policy.setup_cycles, self._mem_spec(),
-               self.policy.dma_overlap)
-        mem = program.pack(inputs)
-        hit = cache.get(key)
-        if hit is None:
-            # plans for superseded program versions can never be reused
-            for stale in [k for k in cache if k[0] != program.version]:
-                del cache[stale]
-            chosen, gains = self._resolve(descs, policy, mem)
-            hit = (chosen, gains) + self._build_runner(descs, chosen)
-            cache[key] = hit
-        chosen, gains, runner, source = hit
-        with self._env():
-            mem = runner(mem)
-        self.stats = {"policy": chosen, "gains": gains,
-                      "n_descriptors": len(descs),
-                      "scheduler": getattr(source, "stats", None)}
-        return program.unpack(mem)
+        with span("ntx.run"):
+            descs = program.descriptors
+            cache = getattr(program, "_plan_cache", None)
+            if cache is None:
+                cache = {}
+                program._plan_cache = cache
+            # cache the resolved policy AND its runner per program
+            # version, so a steady-state loop neither re-prices nor
+            # re-plans the program. backend/autotune are part of the key:
+            # a jitted transport bakes the kernel backend in at trace
+            # time, and measured autotune picks are only valid for the
+            # mode they were raced under
+            key = (program.version, policy or self.policy.policy,
+                   self._n_clusters(), self.policy.transport,
+                   self.policy.backend, self.policy.autotune,
+                   self.policy.spec, self.policy.setup_cycles,
+                   self._mem_spec(), self.policy.dma_overlap)
+            with span("ntx.pack"):
+                mem = program.pack(inputs)
+            hit = cache.get(key)
+            if hit is None:
+                with span("ntx.plan"):
+                    # plans for superseded program versions can never be
+                    # reused
+                    for stale in [k for k in cache
+                                  if k[0] != program.version]:
+                        del cache[stale]
+                    chosen, gains = self._resolve(descs, policy, mem)
+                    hit = (chosen, gains) + self._build_runner(descs, chosen)
+                    cache[key] = hit
+            chosen, gains, runner, source = hit
+            with span("ntx.execute"), self._env():
+                mem = runner(mem)
+            self.stats = {"policy": chosen, "gains": gains,
+                          "n_descriptors": len(descs),
+                          "scheduler": getattr(source, "stats", None)}
+            with span("ntx.unpack"):
+                return program.unpack(mem)

@@ -60,7 +60,7 @@ def main(argv=None):
     prompts = [rng.integers(0, cfg.vocab, args.prompt_len)
                for _ in range(args.batch)]
     out = srv.generate(prompts)
-    print(f"prefill {out['prefill_s']*1e3:.0f} ms | "
+    print(f"time to first tokens {out['prefill_s']*1e3:.0f} ms | "
           f"decode {out['decode_tok_per_s']:.1f} tok/s")
     for i, c in enumerate(out["completions"]):
         print(f"req{i}: {c}")

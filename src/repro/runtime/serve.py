@@ -22,6 +22,7 @@ the layout.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from typing import Any, Dict, List, Optional
 
@@ -31,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import ExecutionPolicy, Executor, Program
+from repro.core.spans import install_gc_span, span
 from repro.models import ArchConfig, Model
 
 
@@ -90,7 +92,15 @@ def _sampler_entry(cache: Dict[tuple, Any], b: int, vocab: int,
 def _run_sampler(ent, logits) -> np.ndarray:
     prog, executor, rows, slots = ent
     res = executor.run(prog, inputs=dict(zip(rows, logits)))
-    return np.asarray([res[s][0] for s in slots], np.float32).astype(np.int64)
+    return _read_slots(res, slots)
+
+
+def _read_slots(res, slots) -> np.ndarray:
+    """The sampled ids, on the host: where the host waits for the
+    device."""
+    with span("serve.readback"):
+        return np.asarray([res[s][0] for s in slots],
+                          np.float32).astype(np.int64)
 
 
 def greedy_argmax_multistream(logits) -> np.ndarray:
@@ -182,24 +192,12 @@ def temperature_sample_multistream(logits, temperature: float, gumbel,
     inputs: Dict[Any, Any] = dict(zip(rows, logits))
     inputs.update(zip(noises, gumbel))
     res = executor.run(prog, inputs=inputs)
-    return np.asarray([res[s][0] for s in slots], np.float32).astype(np.int64)
+    return _read_slots(res, slots)
 
 
-def sampler_stats() -> Dict[str, Any]:
-    """Executor stats of the cached sampling programs (one per shape)."""
-    out: Dict[str, Any] = {}
-    for kind, cache in (("decode", _ARGMAX_PROGRAMS),
-                        ("prefill", _PREFILL_PROGRAMS),
-                        ("temperature", _TEMPERATURE_PROGRAMS)):
-        for key, ent in cache.items():
-            b, vocab = key[0], key[1]
-            name = f"{kind}_b{b}_v{vocab}"
-            if kind == "temperature":
-                name += f"_T{key[2]:g}"       # one entry per (T, floor)
-                if key[3] is not None:
-                    name += f"_floor{key[3]:g}"
-            out[name] = dict(ent[1].stats)
-    return out
+#: the ``call`` stat of ``serve.generate`` spans: one id per call in the
+#: process
+_CALL_IDS = itertools.count()
 
 
 class Server:
@@ -207,6 +205,7 @@ class Server:
         self.cfg, self.params, self.scfg = cfg, params, scfg
         self.model = Model(cfg)
         self._decode = jax.jit(self.model.decode)
+        install_gc_span()
 
     def _sample(self, logits: jnp.ndarray, rng,
                 prefill: bool = False) -> np.ndarray:
@@ -233,47 +232,64 @@ class Server:
 
     def generate(self, prompts: List[np.ndarray],
                  extra: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
-        """Greedy/temperature generation for a batch of same-length prompts."""
+        """Greedy/temperature generation for a batch of same-length prompts.
+
+        Returns the ``completions``, ``prefill_s`` (the call's start to
+        the first tokens on the host: the batch's time to first token)
+        and ``decode_tok_per_s``. Spans (``repro.core.spans``):
+        ``serve.generate`` (stats ``call``, ``batch``, ``prompt_len``)
+        holds ``serve.prefill`` and one ``serve.step`` (``step``,
+        ``active``: rows not done) per loop iteration; each holds a
+        ``serve.forward`` and a ``serve.sample`` (``phase`` prefill or
+        decode), and a step first runs ``serve.emit``, the token
+        bookkeeping.
+        """
         scfg = self.scfg
         rng = np.random.default_rng(scfg.seed)
         b = len(prompts)
         plen = len(prompts[0])
         assert all(len(p) == plen for p in prompts), "same-length prompts"
-        tokens = jnp.asarray(np.stack(prompts), jnp.int32)
-        batch = {"tokens": tokens, "labels": jnp.zeros_like(tokens)}
-        if extra:
-            batch.update(extra)
-
-        t0 = time.perf_counter()
-        logits, cache, fill = self.model.prefill(
-            self.params, batch, cache_len=scfg.max_seq)
-        prefill_s = time.perf_counter() - t0
-
         # the unembedding is padded past the vocabulary (see
         # ArchConfig.padded_vocab); padded ids are never sampled
         vocab = self.cfg.vocab
-        out = [[] for _ in range(b)]
-        done = np.zeros(b, bool)
-        cur = self._sample(logits[:, :vocab], rng, prefill=True)
-        fill = jnp.int32(fill)
-        t1 = time.perf_counter()
-        steps = 0
-        for _ in range(scfg.max_new_tokens):
-            for i in range(b):
-                if not done[i]:
-                    out[i].append(int(cur[i]))
-                    if cur[i] == scfg.eos_token:
-                        done[i] = True
-            if done.all():
-                break
-            logits, cache = self._decode(self.params,
-                                         jnp.asarray(cur[:, None], jnp.int32),
-                                         cache, fill)
-            fill = fill + 1
-            cur = self._sample(logits[:, -1, :vocab], rng)
-            steps += 1
-        decode_s = time.perf_counter() - t1
+        t0 = time.perf_counter()
+        with span("serve.generate", call=next(_CALL_IDS), batch=b,
+                  prompt_len=plen):
+            with span("serve.prefill"):
+                tokens = jnp.asarray(np.stack(prompts), jnp.int32)
+                batch = {"tokens": tokens, "labels": jnp.zeros_like(tokens)}
+                if extra:
+                    batch.update(extra)
+                with span("serve.forward", phase="prefill"):
+                    logits, cache, fill = self.model.prefill(
+                        self.params, batch, cache_len=scfg.max_seq)
+                with span("serve.sample", phase="prefill"):
+                    cur = self._sample(logits[:, :vocab], rng, prefill=True)
+            t1 = time.perf_counter()
+
+            out = [[] for _ in range(b)]
+            done = np.zeros(b, bool)
+            fill = jnp.int32(fill)
+            steps = 0
+            for i in range(scfg.max_new_tokens):
+                with span("serve.step", step=i, active=b - int(done.sum())):
+                    with span("serve.emit"):
+                        for r in range(b):
+                            if not done[r]:
+                                out[r].append(int(cur[r]))
+                                if cur[r] == scfg.eos_token:
+                                    done[r] = True
+                    if done.all():
+                        break
+                    with span("serve.forward", phase="decode"):
+                        logits, cache = self._decode(
+                            self.params, jnp.asarray(cur[:, None], jnp.int32),
+                            cache, fill)
+                    fill = fill + 1
+                    with span("serve.sample", phase="decode"):
+                        cur = self._sample(logits[:, -1, :vocab], rng)
+                    steps += 1
+            decode_s = time.perf_counter() - t1
         return {"completions": out,
-                "prefill_s": prefill_s,
-                "decode_s": decode_s,
+                "prefill_s": t1 - t0,
                 "decode_tok_per_s": (steps * b / decode_s) if decode_s else 0.0}
