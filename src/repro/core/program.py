@@ -28,6 +28,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -43,6 +44,19 @@ _MAX_EXACT_INDEX = 1 << 24
 
 def _align_up(x: int, mult: int) -> int:
     return -(-x // mult) * mult
+
+
+def _flat_index(handles: Sequence["BufferHandle"]) -> np.ndarray:
+    """The image positions of ``handles``' elements, in list order."""
+    return np.concatenate([np.arange(*h.span, dtype=np.int32)
+                           for h in handles])
+
+
+@jax.jit
+def _gather(mem: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """One device op (``jit__gather`` in a trace), compiled once per
+    (image size, index count)."""
+    return mem[idx]
 
 
 class BufferHandle:
@@ -89,7 +103,8 @@ class ProgramResult:
 
     Indexing by handle (or buffer name) returns the buffer's contents as a
     numpy array in its declared shape; ``mem`` is the raw flat jnp image.
-    The device -> host transfer happens once, lazily, for all reads.
+    Indexing copies the whole image to the host once, lazily, for all
+    reads; :meth:`take` copies only the elements of the buffers it names.
     """
 
     def __init__(self, program: "Program", mem: jnp.ndarray):
@@ -106,6 +121,19 @@ class ProgramResult:
         h = self.program.resolve(key)
         lo, hi = h.span
         return self.numpy()[lo:hi].reshape(h.shape)
+
+    def take(self, keys: Sequence[HandleOrName]) -> np.ndarray:
+        """The named buffers' elements on the host, flat, in list order.
+
+        One gather on the device, then one device -> host copy of just
+        those elements: for a few small buffers of a large image (a
+        sampler's slots), where indexing would copy the whole image. If
+        :meth:`numpy` already copied the image, reads from that copy;
+        never fills it."""
+        hs = [self.program.resolve(k) for k in keys]
+        if self._np is not None:
+            return self._np[_flat_index(hs)]
+        return np.asarray(_gather(self.mem, self.program._take_index(hs)))
 
     def read_jax(self, key: HandleOrName) -> jnp.ndarray:
         """Device-side view of one buffer (no host transfer)."""
@@ -138,6 +166,9 @@ class Program:
         # staged once and reused across packs
         self._seg_cache: Dict[int, jnp.ndarray] = {}
         self._gap_cache: Dict[int, jnp.ndarray] = {}
+        # ProgramResult.take's gather indices, staged once per handle
+        # list (a handle's offset never moves once declared)
+        self._take_cache: Dict[Tuple[int, ...], jnp.ndarray] = {}
 
     # -- context manager (purely for the `with Program() as p:` idiom) --
     def __enter__(self) -> "Program":
@@ -427,6 +458,14 @@ class Program:
         if not segs:
             return jnp.zeros(0, jnp.float32)
         return jnp.concatenate(segs)
+
+    def _take_index(self, handles: Sequence[BufferHandle]) -> jnp.ndarray:
+        key = tuple(h.index for h in handles)
+        idx = self._take_cache.get(key)
+        if idx is None:
+            idx = jnp.asarray(_flat_index(handles))
+            self._take_cache[key] = idx
+        return idx
 
     def _gap(self, length: int) -> jnp.ndarray:
         z = self._gap_cache.get(length)

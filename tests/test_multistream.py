@@ -317,6 +317,33 @@ def test_serve_greedy_argmax_multistream():
                                   tied.argmax(-1))
 
 
+def _no_image_copy(self):
+    raise AssertionError("the sampler copied its whole image to the host")
+
+
+@pytest.mark.parametrize("sampler", ["greedy", "pipelined", "temperature"])
+def test_serve_samplers_read_only_the_slots(sampler, monkeypatch):
+    """Each sampler reads its slots through ``ProgramResult.take``, never
+    through the whole-image ``numpy()``, and samples what it did."""
+    import jax
+    from repro.core import ProgramResult
+    from repro.runtime import serve
+    monkeypatch.setattr(ProgramResult, "numpy", _no_image_copy)
+    logits = RNG.standard_normal((5, 300)).astype(np.float32)
+    if sampler == "temperature":
+        t = 0.7
+        g = RNG.gumbel(size=logits.shape).astype(np.float32)
+        got = serve.temperature_sample_multistream(logits, t, g)
+        want = np.argmax(np.log(np.asarray(jax.nn.softmax(
+            jnp.asarray(logits) / t, axis=-1))) + g, axis=-1)
+    else:
+        fn = (serve.greedy_argmax_multistream if sampler == "greedy"
+              else serve.greedy_argmax_pipelined)
+        got, want = fn(logits), logits.argmax(-1)
+    assert got.dtype == np.int64
+    np.testing.assert_array_equal(got, want)
+
+
 def test_train_update_plan_multistream():
     from repro.runtime.train import plan_update_multistream
     params = {"layer0": {"w": np.zeros((64, 64)), "b": np.zeros((64,))},

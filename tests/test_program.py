@@ -116,6 +116,58 @@ def test_pack_validates_sizes():
 
 
 # ----------------------------------------------------------------------
+# ProgramResult.take: read a few buffers without the whole image
+# ----------------------------------------------------------------------
+def _interleaved_program(n=40, lanes=3):
+    """Per lane an input row, its relu and an argmax slot, declared in
+    turn, so every slot sits between rows in the image."""
+    p = Program()
+    outs, slots = [], []
+    for i in range(lanes):
+        r = p.buffer((n,), name=f"r{i}", init=_arr(n))
+        outs.append(p.relu(r))
+        slots.append(p.argmax(outs[-1], name=f"s{i}"))
+    return p, outs, slots
+
+
+def _flat(res, keys):
+    return np.concatenate([res[k].reshape(-1) for k in keys])
+
+
+@pytest.mark.parametrize("policy", ["multistream", "pipeline", "fused"])
+def test_take_reads_what_indexing_reads(policy):
+    p, outs, slots = _interleaved_program()
+    for keys in (slots, outs[::-1], [slots[1], outs[0], "s2"]):
+        res = Executor(policy=policy).run(p)
+        got = res.take(keys)
+        assert res._np is None                  # the image stayed put
+        assert got.dtype == np.float32
+        np.testing.assert_array_equal(got, _flat(res, keys))
+
+
+def test_take_after_numpy_reads_the_host_copy(monkeypatch):
+    p, outs, slots = _interleaved_program()
+    res = Executor(policy="fused").run(p)
+    img = res.numpy()
+    monkeypatch.setattr("repro.core.program._gather", None)  # no device read
+    keys = [outs[2], slots[0]]
+    np.testing.assert_array_equal(res.take(keys), _flat(res, keys))
+    assert res.numpy() is img
+
+
+def test_take_stages_its_index_once():
+    p, outs, slots = _interleaved_program()
+    ex = Executor(policy="multistream")
+    first = ex.run(p).take(slots)
+    (idx,) = p._take_cache.values()
+    np.testing.assert_array_equal(ex.run(p).take(slots), first)
+    assert len(p._take_cache) == 1                   # not staged again
+    ex.run(p).take(slots[:1])                        # another list: its own
+    assert len(p._take_cache) == 2
+    assert p._take_cache[tuple(h.index for h in slots)] is idx
+
+
+# ----------------------------------------------------------------------
 # Descriptor lowering
 # ----------------------------------------------------------------------
 def test_gemm_lowering_matches_canonical_pattern():

@@ -64,6 +64,8 @@ def test_generate_writes_the_span_tree(tmp_path):
     assert _names(inner) == ["serve.forward", "serve.sample",
                              "serve.readback"]
     assert [s[3].get("phase") for s in inner[:2]] == ["prefill", "prefill"]
+    # the readback copies the sampled slots to the host, not the image
+    assert inner[2][3]["bytes"] == 4 * len(prompts)
     # the host's clock agrees: first tokens on the host end the prefill
     assert out["prefill_s"] * 1e9 >= prefill[2] - prefill[1]
     assert out["prefill_s"] * 1e9 <= call[2] - call[1]
@@ -79,6 +81,7 @@ def test_generate_writes_the_span_tree(tmp_path):
         assert [s[3].get("phase") for s in inner[1:3]] == ["decode",
                                                            "decode"]
         (sample,) = [s for s in inner if s[0] == "serve.sample"]
+        assert inner[3][3]["bytes"] == 4 * len(prompts)
         assert "ntx.run" in _names(_inside(sample, found))
 
 
