@@ -4,8 +4,8 @@
 profiler trace runs, the span lands in that trace beside the device's
 events, on the same clock, so an idle gap on the device can be named by
 the host work around it. Nothing is recorded otherwise; a span then
-costs about a microsecond. Stats are fixed when the span opens (ints or
-short strings).
+costs about a microsecond. Stats (ints or short strings) are given when
+the span opens; ``set_metadata`` on the open span adds more.
 
 Names carry the layer as a prefix: ``serve.`` for ``Server.generate``,
 ``ntx.`` for the descriptor-program ``Executor``, ``py.`` for the

@@ -1,5 +1,5 @@
 """repro.models - pure-JAX model zoo (scan-over-layers, remat-able)."""
-from .common import ArchConfig
+from .common import ArchConfig, Yarn
 from .api import Model
 
-__all__ = ["ArchConfig", "Model"]
+__all__ = ["ArchConfig", "Model", "Yarn"]

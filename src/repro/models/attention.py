@@ -19,7 +19,8 @@ import jax.numpy as jnp
 
 from repro.kernels import ops
 from .common import (ArchConfig, Params, apply_rope, apply_mrope,
-                     ctx_constrain_q, ctx_replicate_kv, dense_init)
+                     ctx_constrain_q, ctx_replicate_kv, dense_init,
+                     yarn_attn_scale)
 
 
 # ----------------------------------------------------------------------
@@ -155,13 +156,21 @@ def _mla_qkv(cfg: ArchConfig, p: Params, x: jnp.ndarray, pos):
 
     q = (x @ p["wq"].astype(dt)).reshape(b, s, h, dn + dr).transpose(0, 2, 1, 3)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, pos, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, pos, cfg.rope_theta, cfg.yarn)
 
     ckv = x @ p["wdkv"].astype(dt)                 # (b, s, r + dr)
     c_kv, k_rope = ckv[..., :r], ckv[..., r:]
     c_kv = rmsnorm(c_kv, p["kv_norm"])
-    k_rope = apply_rope(k_rope[:, None], pos, cfg.rope_theta)  # (b,1,s,dr)
+    k_rope = apply_rope(k_rope[:, None], pos, cfg.rope_theta,
+                        cfg.yarn)                      # (b,1,s,dr)
     return q_nope, q_rope, c_kv, k_rope
+
+
+def _mla_scale(cfg: ArchConfig) -> float:
+    """Softmax scale over the (nope + rope) query-key width, with YaRN's
+    ``mscale^2`` where the rope is scaled."""
+    return ((cfg.nope_head_dim + cfg.rope_head_dim) ** -0.5
+            * yarn_attn_scale(cfg.yarn))
 
 
 def _mla_attend(cfg: ArchConfig, p: Params, q_nope, q_rope, c_kv, k_rope,
@@ -181,8 +190,8 @@ def _mla_attend(cfg: ArchConfig, p: Params, q_nope, q_rope, c_kv, k_rope,
 
     q = jnp.concatenate([q_nope, q_rope], -1)
     k = jnp.concatenate([k_nope, k_rope_b], -1)
-    scale = (dn + dr) ** -0.5
-    o = ops.attention(q, k, v, causal=True, scale=scale, kv_len=kv_len)
+    o = ops.attention(q, k, v, causal=True, scale=_mla_scale(cfg),
+                      kv_len=kv_len)
     o = o.transpose(0, 2, 1, 3).reshape(b, s, h * dv)
     return o @ p["wo"].astype(dt)
 
@@ -190,7 +199,8 @@ def _mla_attend(cfg: ArchConfig, p: Params, q_nope, q_rope, c_kv, k_rope,
 def mla_forward(cfg: ArchConfig, p: Params, x: jnp.ndarray, pos,
                 causal: bool = True, kv=None):
     q_nope, q_rope, c_kv, k_rope = _mla_qkv(cfg, p, x, pos)
-    out = _mla_attend(cfg, p, q_nope, q_rope, c_kv, k_rope)
+    with jax.named_scope("mla.attend"):
+        out = _mla_attend(cfg, p, q_nope, q_rope, c_kv, k_rope)
     return out, (c_kv, k_rope)
 
 
@@ -210,13 +220,10 @@ def mla_decode(cfg: ArchConfig, p: Params, x: jnp.ndarray, pos,
         cache["k_rope"], k_rope_new.astype(cache["k_rope"].dtype),
         (0, 0, fill, 0))
     new_cache = {"c_kv": c_kv, "k_rope": k_rope}
-    if absorbed:
-        out = _mla_attend_absorbed(cfg, p, q_nope, q_rope,
-                                   c_kv.astype(dt), k_rope.astype(dt),
-                                   kv_len=fill + s)
-    else:
-        out = _mla_attend(cfg, p, q_nope, q_rope, c_kv.astype(dt),
-                          k_rope.astype(dt), kv_len=fill + s)
+    attend = _mla_attend_absorbed if absorbed else _mla_attend
+    with jax.named_scope("mla.attend"):
+        out = attend(cfg, p, q_nope, q_rope, c_kv.astype(dt),
+                     k_rope.astype(dt), kv_len=fill + s)
     return out, new_cache
 
 
@@ -241,13 +248,17 @@ def _mla_attend_absorbed(cfg: ArchConfig, p: Params, q_nope, q_rope, c_kv,
     wuk = p["wuk"].astype(dt).reshape(r, h, dn)
     # q_lat[b,h,s,r] = q_nope . wuk^T  (absorb the key up-projection)
     q_lat = jnp.einsum("bhsd,rhd->bhsr", q_nope, wuk)
-    scale = (dn + dr) ** -0.5
-    # scores over the latent cache + the shared rope key
-    logits = (jnp.einsum("bhsr,bkr->bhsk", q_lat, c_kv)
-              + jnp.einsum("bhsd,bkd->bhsk", q_rope, k_rope[:, 0])) * scale
+    scale = _mla_scale(cfg)
+    # scores over the latent cache + the shared rope key, in float32 as
+    # the expanded form's
+    f32 = jnp.float32
+    logits = (jnp.einsum("bhsr,bkr->bhsk", q_lat, c_kv,
+                         preferred_element_type=f32)
+              + jnp.einsum("bhsd,bkd->bhsk", q_rope, k_rope[:, 0],
+                           preferred_element_type=f32)) * scale
     kpos = jnp.arange(skv)[None, None, None, :]
     qpos = kv_len - s + jnp.arange(s)[None, None, :, None]
-    logits = jnp.where(kpos <= qpos, logits.astype(jnp.float32), -jnp.inf)
+    logits = jnp.where(kpos <= qpos, logits, -jnp.inf)
     pr = jax.nn.softmax(logits, -1).astype(dt)
     o_lat = jnp.einsum("bhsk,bkr->bhsr", pr, c_kv)      # (b,h,s,r)
     wuv = p["wuv"].astype(dt).reshape(r, h, dv)

@@ -8,6 +8,7 @@ independent of depth (essential for the 512-device CPU dry-run).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -22,6 +23,25 @@ Params = Dict[str, Any]
 # ----------------------------------------------------------------------
 # Config
 # ----------------------------------------------------------------------
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2 applies it:
+    rotation pairs faster than ``beta_fast`` turns over the original
+    context keep their frequency, those slower than ``beta_slow`` turns
+    are divided by ``factor``, a linear ramp blends the pairs between,
+    and the softmax scale is multiplied by ``mscale(mscale_all_dim)^2``."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
@@ -46,13 +66,17 @@ class ArchConfig:
     d_ff_expert: int = 0
     moe_every: int = 1             # MoE FFN on layers where i % moe_every == moe_offset
     moe_offset: int = 0
-    capacity_factor: float = 1.25
+    first_dense_layers: int = 0    # leading layers with a dense MLP (d_ff)
+    norm_topk_prob: bool = True    # renormalise the top-k gates to sum to 1
+    expert_offset: int = 0         # routed experts held here: ids
+    experts_held: int = 0          # [offset, offset + held); 0 -> all
     # --- MLA (deepseek) ---
     mla: bool = False
     kv_lora_rank: int = 0
     rope_head_dim: int = 64
     nope_head_dim: int = 128
     v_head_dim: int = 128
+    yarn: Optional[Yarn] = None    # YaRN scaling of the rope (MLA)
     # --- SSM (mamba2) ---
     ssm: bool = False
     d_state: int = 128
@@ -121,8 +145,13 @@ class ArchConfig:
             return not self.ssm
         return i % self.attn_period == self.attn_offset
 
+    @property
+    def n_held_experts(self) -> int:
+        return self.experts_held or self.n_experts
+
     def is_moe_layer(self, i: int) -> bool:
-        return self.moe and (i % self.moe_every == self.moe_offset)
+        return (self.moe and i >= self.first_dense_layers
+                and i % self.moe_every == self.moe_offset)
 
     def scaled(self, **overrides) -> "ArchConfig":
         return dataclasses.replace(self, **overrides)
@@ -184,12 +213,47 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
                             / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, pos: jnp.ndarray, theta: float) -> jnp.ndarray:
+def yarn_freqs(head_dim: int, theta: float, yarn: Yarn) -> np.ndarray:
+    """Inverse frequencies of YaRN: pair ``i`` blends the original
+    frequency (weight ``1 - ramp_i``) with it divided by ``factor``; the
+    ramp runs linearly from 0 to 1 between the pairs that turn
+    ``beta_fast`` and ``beta_slow`` times over the original context."""
+    def pair_at(turns):
+        return (head_dim * math.log(yarn.original_max_position
+                                    / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    lo = max(math.floor(pair_at(yarn.beta_fast)), 0)
+    hi = min(math.ceil(pair_at(yarn.beta_slow)), head_dim - 1)
+    if lo == hi:
+        hi += 0.001
+    extra = 1.0 / theta ** (np.arange(0, head_dim, 2, dtype=np.float64)
+                            / head_dim)
+    ramp = np.clip((np.arange(head_dim // 2) - lo) / (hi - lo), 0.0, 1.0)
+    return (extra / yarn.factor * ramp + extra * (1.0 - ramp)).astype(
+        np.float32)
+
+
+def yarn_attn_scale(yarn: Optional[Yarn]) -> float:
+    """Factor on the attention softmax scale: ``mscale(mscale_all_dim)^2``."""
+    if yarn is None or not yarn.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
+
+
+def apply_rope(x: jnp.ndarray, pos: jnp.ndarray, theta: float,
+               yarn: Optional[Yarn] = None) -> jnp.ndarray:
     """x: (b, h, s, d); pos: (b, s) int32 absolute positions."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                       # (d/2,)
+    if yarn is None:
+        freqs = rope_freqs(d, theta)                   # (d/2,)
+    else:
+        freqs = jnp.asarray(yarn_freqs(d, theta, yarn))
     ang = pos[:, None, :, None].astype(jnp.float32) * freqs  # (b,1,s,d/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
     return out.astype(x.dtype)

@@ -1,18 +1,25 @@
-"""Mixture-of-Experts FFN with sort-based, static-shape dispatch.
+"""Mixture-of-experts FFN: dropless, over the routed experts held here.
 
-Design constraints (see DESIGN.md §7):
-  * static shapes only (SPMD dry-run; no ragged ops),
-  * active-FLOP-proportional compute — the capacity buffer is
-    ``top_k * S / E * capacity_factor`` slots per sequence, so HLO FLOPs in
-    cost_analysis reflect the real MoE compute (6*N_active*D accounting),
-  * sharding: experts across the ``model`` axis (EP); token groups (= batch
-    rows) across ``data``; the dispatch sort stays group-local.
+Routing keeps the published width: every token scores all ``n_experts``
+(softmax in float32) and takes its ``top_k`` greedily; the gates are
+renormalised to sum to one only where ``cfg.norm_topk_prob``.
 
-Routing uses top-k softmax gating with first-wins capacity dropping and the
-standard load-balance auxiliary loss. Dispatch/combine are scatter/gather by
-flat indices (`mode=drop` handles capacity overflow), which is the
-TPU-friendly static realization of the paper's "streaming" philosophy — no
-data-dependent control flow anywhere.
+This chip holds routed experts ``[expert_offset, expert_offset +
+n_held_experts)`` (by default all of them) and computes their part of the
+result, as one member of an expert-parallel group does before the
+exchange; assignments to experts held elsewhere add nothing here. Shared
+experts are computed once, for every token.
+
+Nothing is dropped. The ``b*s*top_k`` assignments of the call are grouped
+by held expert across the whole batch (one stable sort), and each expert
+matrix is one grouped GEMM (``jax.lax.ragged_dot``) over the held
+experts' rows. Shapes stay static: the row buffer holds the most that
+could be routed here, ``b*s*min(top_k, n_held_experts)``, and the GEMM's
+work follows the rows its groups hold.
+
+Named scopes: ``moe.route`` (router, top-k, grouping), ``moe.experts``
+(the grouped GEMMs), ``moe.combine`` (gate-weighted sum per token, shared
+experts).
 """
 from __future__ import annotations
 
@@ -26,9 +33,9 @@ from .common import ArchConfig, Params, dense_init
 
 def moe_params(cfg: ArchConfig, key) -> Params:
     d, ffe = cfg.d_model, cfg.d_ff_expert
-    e = cfg.n_experts
+    e = cfg.n_held_experts
     ks = jax.random.split(key, 5)
-    p = {"router": dense_init(ks[0], (d, e), 0, cfg.pdtype),
+    p = {"router": dense_init(ks[0], (d, cfg.n_experts), 0, cfg.pdtype),
          "w1": dense_init(ks[1], (e, d, ffe), 1, cfg.pdtype),
          "w2": dense_init(ks[2], (e, ffe, d), 1, cfg.pdtype),
          "w3": dense_init(ks[3], (e, d, ffe), 1, cfg.pdtype)}
@@ -41,69 +48,61 @@ def moe_params(cfg: ArchConfig, key) -> Params:
     return p
 
 
-def _capacity(cfg: ArchConfig, s: int) -> int:
-    c = int(cfg.top_k * s * cfg.capacity_factor / cfg.n_experts)
-    return max(cfg.top_k, c)
+def _gmm(rows: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray
+         ) -> jnp.ndarray:
+    """Grouped GEMM: rows ``[sum(sizes[:g]), sum(sizes[:g+1]))`` times
+    ``w[g]``; rows past ``sum(sizes)`` are not computed."""
+    return jax.lax.ragged_dot(rows, w.astype(rows.dtype), sizes)
 
 
 def apply_moe(cfg: ArchConfig, p: Params, x: jnp.ndarray
-              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x: (b, s, d) -> (y, aux_loss). Groups = batch rows."""
+              ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """x: (b, s, d) -> (y, aux_loss, load); ``load`` (int32, one per held
+    expert) counts the assignments each received."""
     dt = cfg.cdtype
     b, s, d = x.shape
-    e, k = cfg.n_experts, cfg.top_k
-    cap = _capacity(cfg, s)
+    e, k, held = cfg.n_experts, cfg.top_k, cfg.n_held_experts
+    n = b * s * k
     x = x.astype(dt)
 
-    # --- routing (fp32 for stable softmax) ---
-    logits = (x @ p["router"].astype(dt)).astype(jnp.float32)  # (b,s,e)
-    probs = jax.nn.softmax(logits, -1)
-    gate, expert = jax.lax.top_k(probs, k)                     # (b,s,k)
-    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("moe.route"):
+        logits = (x @ p["router"].astype(dt)).astype(jnp.float32)  # (b,s,e)
+        probs = jax.nn.softmax(logits, -1)
+        gate, expert = jax.lax.top_k(probs, k)                     # (b,s,k)
+        if cfg.norm_topk_prob:
+            gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+        # load-balance aux loss (Switch-style): e * sum_e f_e * p_e
+        me = probs.mean(1)                                          # (b,e)
+        ce = jax.nn.one_hot(expert[..., 0], e, dtype=jnp.float32).mean(1)
+        aux = (me * ce).sum(-1).mean() * e
 
-    # load-balance aux loss (Switch-style): e * sum_e f_e * p_e
-    me = probs.mean(1)                                          # (b,e)
-    ce = jax.nn.one_hot(expert[..., 0], e, dtype=jnp.float32).mean(1)
-    aux = (me * ce).sum(-1).mean() * e
+        # group the assignments by held expert, the others last
+        local = expert.reshape(n) - cfg.expert_offset
+        is_held = (local >= 0) & (local < held)
+        group = jnp.where(is_held, local, held)
+        order = jnp.argsort(group, stable=True)          # row -> assignment
+        bounds = jnp.searchsorted(group[order], jnp.arange(held + 1),
+                                  side="left").astype(jnp.int32)
+        load = jnp.diff(bounds)                           # (held,)
+        row = jnp.zeros((n,), jnp.int32).at[order].set(
+            jnp.arange(n, dtype=jnp.int32), unique_indices=True)
+        cap = b * s * min(k, held)
+        src = order[:cap] // k                            # row -> token
 
-    # --- dispatch: sort tokens by expert within each group ---
-    flat_e = expert.reshape(b, s * k)                           # (b, sk)
-    order = jnp.argsort(flat_e, axis=-1)                        # stable
-    e_sorted = jnp.take_along_axis(flat_e, order, -1)
-    tok_sorted = order // k                                     # source token
-    gate_sorted = jnp.take_along_axis(gate.reshape(b, s * k), order, -1)
+    with jax.named_scope("moe.experts"):
+        xs = jnp.take(x.reshape(b * s, d), src, axis=0)   # (cap, d)
+        h = jax.nn.silu(_gmm(xs, p["w1"], load)) * _gmm(xs, p["w3"], load)
+        ys = _gmm(h, p["w2"], load)                       # (cap, d)
 
-    # position of each sorted entry within its expert's capacity buffer
-    seg_start = jax.vmap(lambda es: jnp.searchsorted(es, jnp.arange(e),
-                                                     side="left"))(e_sorted)
-    pos_in_e = jnp.arange(s * k)[None, :] - jnp.take_along_axis(
-        seg_start, e_sorted, -1)
-    keep = pos_in_e < cap
-    slot = jnp.where(keep, e_sorted * cap + pos_in_e, e * cap)  # drop slot
-
-    # gather tokens into (b, e*cap, d) expert buffers
-    src = jnp.take_along_axis(x, tok_sorted[..., None], 1)      # (b, sk, d)
-    buf = jnp.zeros((b, e * cap + 1, d), dt)
-    buf = jax.vmap(lambda bb, sl, sr: bb.at[sl].set(sr, mode="drop"))(
-        buf, slot, src)
-    buf = buf[:, :e * cap].reshape(b, e, cap, d)
-
-    # --- expert FFN (batched GEMMs over the expert axis) ---
-    h = (jax.nn.silu(jnp.einsum("becd,edf->becf", buf, p["w1"].astype(dt)))
-         * jnp.einsum("becd,edf->becf", buf, p["w3"].astype(dt)))
-    y_e = jnp.einsum("becf,efd->becd", h, p["w2"].astype(dt))
-    y_flat = y_e.reshape(b, e * cap, d)
-    y_flat = jnp.concatenate([y_flat, jnp.zeros((b, 1, d), dt)], 1)
-
-    # --- combine: gather back, weight, scatter-add per source token ---
-    slot_g = jnp.where(keep, slot, e * cap)
-    out_tok = jnp.take_along_axis(y_flat, slot_g[..., None], 1)  # (b, sk, d)
-    out_tok = out_tok * (gate_sorted * keep)[..., None].astype(dt)
-    y = jnp.zeros((b, s, d), dt)
-    y = jax.vmap(lambda yy, ti, ot: yy.at[ti].add(ot))(y, tok_sorted, out_tok)
-
-    if cfg.n_shared_experts:
-        sh = p["shared"]
-        hs = jax.nn.silu(x @ sh["w1"].astype(dt)) * (x @ sh["w3"].astype(dt))
-        y = y + hs @ sh["w2"].astype(dt)
-    return y, aux.astype(jnp.float32)
+    with jax.named_scope("moe.combine"):
+        at = jnp.where(is_held, row, 0)
+        yt = jnp.where(is_held[:, None], jnp.take(ys, at, axis=0), 0)
+        w = gate.reshape(n, 1)
+        y = (yt.astype(jnp.float32) * w).reshape(b, s, k, d).sum(2)
+        y = y.astype(dt)
+        if cfg.n_shared_experts:
+            sh = p["shared"]
+            hs = (jax.nn.silu(x @ sh["w1"].astype(dt))
+                  * (x @ sh["w3"].astype(dt)))
+            y = y + hs @ sh["w2"].astype(dt)
+    return y, aux.astype(jnp.float32), load

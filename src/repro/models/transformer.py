@@ -14,6 +14,7 @@ Entry points: init / loss / prefill / decode_step — see ``api.py``.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -140,7 +141,7 @@ def _apply_kind(cfg: ArchConfig, kind: str, p: Params, x, pos, aux):
         return x, aux
     h = apply_norm(cfg, p["norm2"], x)
     if ffn == "moe":
-        o, a = moe_mod.apply_moe(cfg, p["ffn"], h)
+        o, a, _ = moe_mod.apply_moe(cfg, p["ffn"], h)
         aux = aux + a
         return x + o, aux
     # residual add fused into the MLP's second-GEMM store epilogue
@@ -262,6 +263,10 @@ def _kind_cache(cfg: ArchConfig, kind: str, batch: int, seq: int, dtype):
 
 def init_cache(cfg: ArchConfig, batch: int, seq: int,
                dtype=jnp.bfloat16) -> Dict[str, Any]:
+    """Per layer kind, its layers' caches stacked. MoE configs also carry
+    ``moe_load`` (int32, one per held expert): the assignments each held
+    expert received, summed over layers and over the prefill and every
+    decode step that used the cache, on the device."""
     sched, kinds, _ = layer_schedule(cfg)
     caches = {}
     for kind in kinds:
@@ -269,6 +274,8 @@ def init_cache(cfg: ArchConfig, batch: int, seq: int,
         one = _kind_cache(cfg, kind, batch, seq, dtype)
         caches[kind] = jax.tree.map(
             lambda a: jnp.broadcast_to(a[None], (count,) + a.shape), one)
+    if cfg.moe:
+        caches["moe_load"] = jnp.zeros((cfg.n_held_experts,), jnp.int32)
     return caches
 
 
@@ -307,12 +314,14 @@ def decode_step(cfg: ArchConfig, params: Params, tokens: jnp.ndarray,
         if ffn != "none":
             h = apply_norm(cfg, p["norm2"], x)
             if ffn == "moe":
-                o, _ = moe_mod.apply_moe(cfg, p["ffn"], h)
+                o, _, load = moe_mod.apply_moe(cfg, p["ffn"], h)
                 x = x + o
             else:
                 x = apply_mlp(cfg, p["ffn"], h, residual=x)
         caches = dict(caches)
         caches[kind] = _update_tree(caches[kind], new_c, idx)
+        if ffn == "moe":
+            caches["moe_load"] = caches["moe_load"] + load
         return x, caches
 
     if cfg.unroll:
@@ -362,23 +371,46 @@ def prefill(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
     sequential chunks (serving-style chunked prefill) — divides peak
     activation memory by the chunk count at unchanged total compute."""
     mb = max(1, cfg.prefill_microbatch)
-    if mb > 1 and batch["tokens"].shape[0] % mb == 0:
-        def split(path, leaf):
-            name = getattr(path[-1], "key", None)
-            if name == "pos3":
-                x = leaf.reshape(leaf.shape[0], mb, -1, *leaf.shape[2:])
-                return jnp.moveaxis(x, 1, 0)
-            return leaf.reshape(mb, -1, *leaf.shape[1:])
-        chunks = jax.tree_util.tree_map_with_path(split, batch)
-        logits, caches, fill = jax.lax.map(
-            lambda c: _prefill_impl(cfg, params, c, cache_len), chunks)
-        logits = logits.reshape(-1, logits.shape[-1])
-        # cache leaves: (mb, L, b/mb, ...) -> (L, b, ...)
-        caches = jax.tree.map(
-            lambda a: jnp.moveaxis(a, 0, 1).reshape(
-                a.shape[1], -1, *a.shape[3:]), caches)
-        return logits, caches, batch["tokens"].shape[1]
+    b, s = batch["tokens"].shape
+    if mb > 1 and b % mb == 0:
+        logits, cache = _prefill_chunked(cfg, params, batch, cache_len or s,
+                                         mb)
+        return logits, cache, s
     return _prefill_impl(cfg, params, batch, cache_len)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 3, 4))
+def _prefill_chunked(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
+                     cache_len: int, mb: int):
+    """``prefill`` of ``mb`` chunks of the batch, one after another, each
+    chunk's cache written into one whole-batch cache in place.
+    Returns (logits, cache)."""
+    b = batch["tokens"].shape[0]
+    bc = b // mb
+
+    def split(path, leaf):
+        if getattr(path[-1], "key", None) == "pos3":
+            x = leaf.reshape(leaf.shape[0], mb, bc, *leaf.shape[2:])
+            return jnp.moveaxis(x, 1, 0)
+        return leaf.reshape(mb, bc, *leaf.shape[1:])
+
+    def body(cache, step):
+        i, chunk = step
+        logits, part, _ = _prefill_impl(cfg, params, chunk, cache_len)
+        cache = dict(cache)
+        for name, new in part.items():
+            if name == "moe_load":
+                cache[name] = cache[name] + new
+            else:
+                cache[name] = jax.tree.map(
+                    lambda full, n: jax.lax.dynamic_update_slice_in_dim(
+                        full, n, i * bc, axis=1), cache[name], new)
+        return cache, logits
+
+    chunks = jax.tree_util.tree_map_with_path(split, batch)
+    cache, logits = jax.lax.scan(body, init_cache(cfg, b, cache_len),
+                                 (jnp.arange(mb), chunks))
+    return logits.reshape(b, -1), cache
 
 
 def _prefill_impl(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
@@ -401,12 +433,14 @@ def _prefill_impl(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
         if ffn != "none":
             h = apply_norm(cfg, p["norm2"], x)
             if ffn == "moe":
-                o, _ = moe_mod.apply_moe(cfg, p["ffn"], h)
+                o, _, load = moe_mod.apply_moe(cfg, p["ffn"], h)
                 x = x + o
             else:
                 x = apply_mlp(cfg, p["ffn"], h, residual=x)
         caches = dict(caches)
         caches[kind] = _update_tree(caches[kind], new_c, idx)
+        if ffn == "moe":
+            caches["moe_load"] = caches["moe_load"] + load
         return x, caches
 
     if cfg.unroll:

@@ -204,7 +204,10 @@ class Server:
     def __init__(self, cfg: ArchConfig, params, scfg: ServeConfig):
         self.cfg, self.params, self.scfg = cfg, params, scfg
         self.model = Model(cfg)
-        self._decode = jax.jit(self.model.decode)
+        # the cache is donated: each step updates it in place
+        self._decode = jax.jit(self.model.decode,
+                               static_argnames=("absorbed_mla",),
+                               donate_argnames=("cache",))
         install_gc_span()
 
     def _sample(self, logits: jnp.ndarray, rng,
@@ -236,9 +239,14 @@ class Server:
 
         Returns the ``completions``, ``prefill_s`` (the call's start to
         the first tokens on the host: the batch's time to first token)
-        and ``decode_tok_per_s``. Spans (``repro.core.spans``):
-        ``serve.generate`` (stats ``call``, ``batch``, ``prompt_len``)
-        holds ``serve.prefill`` and one ``serve.step`` (``step``,
+        and ``decode_tok_per_s``; MoE configs also return
+        ``moe_assigned``, the assignments routed to the held experts over
+        the call's layers and forward passes, and
+        ``moe_max_expert_load``, the most of them one held expert
+        received (counted on the device, read once at the end). Spans
+        (``repro.core.spans``): ``serve.generate`` (stats ``call``,
+        ``batch``, ``prompt_len``, and the two MoE counts) holds
+        ``serve.prefill`` and one ``serve.step`` (``step``,
         ``active``: rows not done) per loop iteration; each holds a
         ``serve.forward`` and a ``serve.sample`` (``phase`` prefill or
         decode), and a step first runs ``serve.emit``, the token
@@ -254,7 +262,7 @@ class Server:
         vocab = self.cfg.vocab
         t0 = time.perf_counter()
         with span("serve.generate", call=next(_CALL_IDS), batch=b,
-                  prompt_len=plen):
+                  prompt_len=plen) as call:
             with span("serve.prefill"):
                 tokens = jnp.asarray(np.stack(prompts), jnp.int32)
                 batch = {"tokens": tokens, "labels": jnp.zeros_like(tokens)}
@@ -284,12 +292,20 @@ class Server:
                     with span("serve.forward", phase="decode"):
                         logits, cache = self._decode(
                             self.params, jnp.asarray(cur[:, None], jnp.int32),
-                            cache, fill)
+                            cache, fill, absorbed_mla=self.cfg.mla_absorb)
                     fill = fill + 1
                     with span("serve.sample", phase="decode"):
                         cur = self._sample(logits[:, -1, :vocab], rng)
                     steps += 1
             decode_s = time.perf_counter() - t1
-        return {"completions": out,
-                "prefill_s": t1 - t0,
-                "decode_tok_per_s": (steps * b / decode_s) if decode_s else 0.0}
+            result = {"completions": out,
+                      "prefill_s": t1 - t0,
+                      "decode_tok_per_s": (steps * b / decode_s)
+                      if decode_s else 0.0}
+            if "moe_load" in cache:
+                load = np.asarray(cache["moe_load"])
+                counts = {"moe_assigned": int(load.sum()),
+                          "moe_max_expert_load": int(load.max())}
+                result.update(counts)
+                call.set_metadata(**counts)
+        return result

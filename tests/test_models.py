@@ -56,6 +56,10 @@ def test_reduced_smoke_decode(arch):
                                             jnp.int32(fill))
     assert logits2.shape == (b, 1, cfg.padded_vocab)
     assert np.isfinite(np.asarray(logits2, np.float32)).all(), arch
+    if cfg.moe:     # every assignment of the prefill and the step counted
+        moe_layers = sum(map(cfg.is_moe_layer, range(cfg.n_layers)))
+        assert int(cache2["moe_load"].sum()) == (
+            b * (s + 1) * cfg.top_k * moe_layers), arch
 
 
 @pytest.mark.parametrize("arch", configs.ARCHS)

@@ -63,6 +63,8 @@ def test_mla_absorbed_equals_expanded():
     from repro.models import Model
     cfg = configs.get_reduced("deepseek-v2-lite-16b").scaled(
         compute_dtype="float32", param_dtype="float32")
+    # both forms carry YaRN's rope and softmax scale, past a dense layer 0
+    assert cfg.yarn is not None and cfg.first_dense_layers == 1
     m = Model(cfg)
     p = m.init(0)
     rng = np.random.default_rng(0)
