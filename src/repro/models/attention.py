@@ -1,11 +1,15 @@
 """Attention blocks: GQA (llama-class) and MLA (deepseek-v2 class).
 
-Both expose the same three entry points:
+Both expose the same entry points:
   * ``*_params(cfg, key)``                      parameter pytree
   * ``*_forward(cfg, p, x, pos[, kv])``         training / prefill; returns
                                                 (out, cache_entry)
-  * ``*_decode(cfg, p, x, pos, cache, fill)``   single/few-token decode with
-                                                a pre-allocated cache
+  * ``*_decode(cfg, p, x, pos, cache, fill)``   single/few-token decode over
+                                                a pre-allocated cache that
+                                                holds positions < fill;
+                                                returns (out, this step's
+                                                entries), which the caller
+                                                stores with ``write_cache``
 
 Attention math runs through ``repro.kernels.ops.attention`` — the NTX
 MAX+MAC streaming reduction (flash kernel on TPU, oracle on CPU).
@@ -109,19 +113,33 @@ def gqa_init_cache(cfg: ArchConfig, batch: int, seq: int, dtype) -> Params:
 
 def gqa_decode(cfg: ArchConfig, p: Params, x: jnp.ndarray, pos,
                cache: Params, fill: jnp.ndarray):
-    """x: (b, s_new, d); cache k/v (b, hkv, S, hd); fill = current length."""
+    """x: (b, s_new, d); cache k/v (b, hkv, S, hd); fill = current length.
+    Returns (out, {"k", "v"}: (b, hkv, s_new, hd))."""
     dt = cfg.cdtype
     b, s, _ = x.shape
     q, k_new, v_new = _qkv(cfg, p, x)
     q, k_new = _rope_qk(cfg, q, k_new, pos)
-    k = jax.lax.dynamic_update_slice(cache["k"], k_new.astype(cache["k"].dtype),
-                                     (0, 0, fill, 0))
-    v = jax.lax.dynamic_update_slice(cache["v"], v_new.astype(cache["v"].dtype),
-                                     (0, 0, fill, 0))
-    o = ops.attention(q, k.astype(dt), v.astype(dt), causal=True,
+    new = {"k": k_new, "v": v_new}
+    c = write_cache(cache, new, fill)
+    o = ops.attention(q, c["k"].astype(dt), c["v"].astype(dt), causal=True,
                       kv_len=fill + s)
     o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * cfg.hd)
-    return o @ p["wo"].astype(dt), {"k": k, "v": v}
+    return o @ p["wo"].astype(dt), new
+
+
+def write_cache(cache: Params, new: Params, fill, layer=0) -> Params:
+    """``cache`` with ``new`` (entries from ``*_decode``, of the same rank)
+    written from sequence position ``fill``: one dynamic_update_slice a
+    leaf, in place where ``cache`` is donated. Every cache leaf has its
+    sequence axis second to last; ``layer`` offsets the leading axis, the
+    first of the layers ``new`` holds where ``cache`` is a stack."""
+    out = dict(cache)
+    for name, n in new.items():
+        start = [layer] + [0] * (n.ndim - 1)
+        start[-2] = fill
+        out[name] = jax.lax.dynamic_update_slice(
+            cache[name], n.astype(cache[name].dtype), start)
+    return out
 
 
 # ----------------------------------------------------------------------
@@ -211,24 +229,26 @@ def mla_init_cache(cfg: ArchConfig, batch: int, seq: int, dtype) -> Params:
 
 def mla_decode(cfg: ArchConfig, p: Params, x: jnp.ndarray, pos,
                cache: Params, fill: jnp.ndarray, absorbed: bool = False):
+    """Returns (out, {"c_kv": (b, s_new, r), "k_rope": (b, 1, s_new, dr)}).
+    The absorbed form attends over the cache and the new entries as they
+    are; the expanded form over a copy of the cache with them written."""
     dt = cfg.cdtype
     s = x.shape[1]
     q_nope, q_rope, c_kv_new, k_rope_new = _mla_qkv(cfg, p, x, pos)
-    c_kv = jax.lax.dynamic_update_slice(
-        cache["c_kv"], c_kv_new.astype(cache["c_kv"].dtype), (0, fill, 0))
-    k_rope = jax.lax.dynamic_update_slice(
-        cache["k_rope"], k_rope_new.astype(cache["k_rope"].dtype),
-        (0, 0, fill, 0))
-    new_cache = {"c_kv": c_kv, "k_rope": k_rope}
-    attend = _mla_attend_absorbed if absorbed else _mla_attend
+    new = {"c_kv": c_kv_new, "k_rope": k_rope_new}
     with jax.named_scope("mla.attend"):
-        out = attend(cfg, p, q_nope, q_rope, c_kv.astype(dt),
-                     k_rope.astype(dt), kv_len=fill + s)
-    return out, new_cache
+        if absorbed:
+            out = _mla_attend_absorbed(cfg, p, q_nope, q_rope, cache, new,
+                                       fill)
+        else:
+            c = write_cache(cache, new, fill)
+            out = _mla_attend(cfg, p, q_nope, q_rope, c["c_kv"].astype(dt),
+                              c["k_rope"].astype(dt), kv_len=fill + s)
+    return out, new
 
 
-def _mla_attend_absorbed(cfg: ArchConfig, p: Params, q_nope, q_rope, c_kv,
-                         k_rope, kv_len):
+def _mla_attend_absorbed(cfg: ArchConfig, p: Params, q_nope, q_rope,
+                         cache: Params, new: Params, fill):
     """Absorbed-matmul MLA decode (beyond-paper §Perf optimization).
 
     Instead of expanding the latent cache to per-head K/V (which costs
@@ -237,30 +257,46 @@ def _mla_attend_absorbed(cfg: ArchConfig, p: Params, q_nope, q_rope, c_kv,
     space. Decode flops drop from O(skv*h*(dn+dv)*r) to O(skv*h*(r+dr)) per
     query — the memory term drops by ~h x as well since the latent is read
     once instead of h expanded heads.
+
+    The keys are the cache's positions < ``fill`` and this step's entries
+    ``new``, causally, each read where it lies: the cache is an operand of
+    the scores and the values, and no copy of it is made.
     """
     dt = cfg.cdtype
     b, h, s, dn = q_nope.shape
     r = cfg.kv_lora_rank
-    dr = cfg.rope_head_dim
     dv = cfg.v_head_dim
-    skv = c_kv.shape[1]
+    skv = cache["c_kv"].shape[1]
+    c_kv, k_rope = cache["c_kv"].astype(dt), cache["k_rope"].astype(dt)
+    # this step's entries rounded as the cache will hold them
+    c_new, k_new = (new[n].astype(cache[n].dtype).astype(dt)
+                    for n in ("c_kv", "k_rope"))
 
     wuk = p["wuk"].astype(dt).reshape(r, h, dn)
     # q_lat[b,h,s,r] = q_nope . wuk^T  (absorb the key up-projection)
     q_lat = jnp.einsum("bhsd,rhd->bhsr", q_nope, wuk)
     scale = _mla_scale(cfg)
-    # scores over the latent cache + the shared rope key, in float32 as
+    # scores over the latent keys + the shared rope key, in float32 as
     # the expanded form's
     f32 = jnp.float32
-    logits = (jnp.einsum("bhsr,bkr->bhsk", q_lat, c_kv,
-                         preferred_element_type=f32)
-              + jnp.einsum("bhsd,bkd->bhsk", q_rope, k_rope[:, 0],
-                           preferred_element_type=f32)) * scale
-    kpos = jnp.arange(skv)[None, None, None, :]
-    qpos = kv_len - s + jnp.arange(s)[None, None, :, None]
-    logits = jnp.where(kpos <= qpos, logits, -jnp.inf)
-    pr = jax.nn.softmax(logits, -1).astype(dt)
-    o_lat = jnp.einsum("bhsk,bkr->bhsr", pr, c_kv)      # (b,h,s,r)
+
+    def scores(c, k):
+        return (jnp.einsum("bhsr,bkr->bhsk", q_lat, c,
+                           preferred_element_type=f32)
+                + jnp.einsum("bhsd,bkd->bhsk", q_rope, k[:, 0],
+                             preferred_element_type=f32)) * scale
+
+    old = jnp.where(jnp.arange(skv) < fill, scores(c_kv, k_rope), -jnp.inf)
+    causal = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
+    cur = jnp.where(causal, scores(c_new, k_new), -jnp.inf)
+    # one softmax over both key sets, each kept where it lies
+    m = jnp.maximum(old.max(-1, keepdims=True), cur.max(-1, keepdims=True))
+    e_old, e_cur = jnp.exp(old - m), jnp.exp(cur - m)
+    z = e_old.sum(-1, keepdims=True) + e_cur.sum(-1, keepdims=True)
+    o_lat = (jnp.einsum("bhsk,bkr->bhsr", (e_old / z).astype(dt), c_kv,
+                        preferred_element_type=f32)
+             + jnp.einsum("bhsk,bkr->bhsr", (e_cur / z).astype(dt), c_new,
+                          preferred_element_type=f32)).astype(dt)
     wuv = p["wuv"].astype(dt).reshape(r, h, dv)
     o = jnp.einsum("bhsr,rhd->bhsd", o_lat, wuv)        # absorb W_uv
     o = o.transpose(0, 2, 1, 3).reshape(b, s, h * dv)

@@ -130,9 +130,7 @@ def _decoder(cfg: ArchConfig, params: Params, tokens, enc,
         x = x + o
         h = apply_norm(cfg, p["norm2"], x)
         x = apply_mlp(cfg, p["ffn"], h, residual=x)
-        new_c = {"k": kv_new["k"], "v": kv_new["v"], "ck": c["ck"],
-                 "cv": c["cv"]}
-        return x, new_c
+        return x, attn.write_cache(c, kv_new, fill)
 
     x, new_cache = scan_or_unroll(cfg, body, x,
                                   (params["dec_layers"], cache))

@@ -1,14 +1,22 @@
 """Generic decoder-only LM covering all assigned decoder families.
 
-Layer heterogeneity (jamba's 1:7 attention:mamba interleave, periodic MoE)
-is handled as ONE ``lax.scan`` over all layers whose body ``lax.switch``-es
-between the distinct layer *kinds* (attn/ssm mixer x moe/mlp/none ffn).
-Parameters and decode caches are stored per-kind (stacked over that kind's
-layers) and dynamically indexed each step. This keeps HLO size O(#kinds)
-AND gives true per-layer remat granularity — an unrolled heterogeneous
-period keeps every sublayer's working set live during its backward
-(measured 4x worse on jamba; nested jax.checkpoint inside a checkpointed
-scan body does not recover it).
+Layer heterogeneity (jamba's 1:7 attention:mamba interleave, periodic MoE,
+deepseek's leading dense layer) is handled in training and prefill as ONE
+``lax.scan`` over all layers whose body ``lax.switch``-es between the
+distinct layer *kinds* (attn/ssm mixer x moe/mlp/none ffn). Parameters and
+decode caches are stored per-kind (stacked over that kind's layers) and
+dynamically indexed each step. This keeps HLO size O(#kinds) AND gives
+true per-layer remat granularity — an unrolled heterogeneous period keeps
+every sublayer's working set live during its backward (measured 4x worse
+on jamba; nested jax.checkpoint inside a checkpointed scan body does not
+recover it).
+
+Decode takes no switch: a branch returns every kind's cache, so each layer
+would copy the stacks of the kinds it does not own. ``decode_step`` scans
+each segment of ``decode_segments`` (the leading dense layers, then the
+repeating rest) with one period of layers unrolled in the body. The scans
+only read the caches; after each, its layers' new positions are written
+into their kinds' stacks in place.
 
 Entry points: init / loss / prefill / decode_step — see ``api.py``.
 """
@@ -20,10 +28,11 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .common import (ArchConfig, Params, apply_mlp, apply_norm, chunked_xent,
                      embed_params, embed_tokens, mlp_params, norm_params,
-                     remat_wrap, sp_constrain, unembed)
+                     remat_wrap, scan_or_unroll, sp_constrain, unembed)
 from . import attention as attn
 from . import moe as moe_mod
 from . import ssm as ssm_mod
@@ -279,8 +288,30 @@ def init_cache(cfg: ArchConfig, batch: int, seq: int,
     return caches
 
 
+def decode_segments(cfg: ArchConfig):
+    """The layer schedule as the runs ``decode_step`` scans: the leading
+    ``first_dense_layers``, then the rest, each split into repeats of its
+    shortest period of kinds. Returns [(period's kinds, idx_in_kind of
+    shape (repeats, len(period)))]; each kind's layers in a run are
+    consecutive in its stack."""
+    sched, _, idx_in_kind = layer_schedule(cfg)
+    fd = cfg.first_dense_layers
+    segments = []
+    for lo, hi in ((0, fd), (fd, cfg.n_layers)):
+        run = sched[lo:hi]
+        if not run:
+            continue
+        p = next(p for p in range(1, len(run) + 1)
+                 if run == run[:p] * (len(run) // p))
+        segments.append((run[:p], np.asarray(idx_in_kind[lo:hi],
+                                             np.int32).reshape(-1, p)))
+    return segments
+
+
 def _mixer_decode(cfg: ArchConfig, kind: str, p, h, pos, c, fill,
                   absorbed_mla: bool):
+    """(out, this step's entries): new positions for attention, whole
+    states for a state-space layer (they have no sequence axis)."""
     mixer = kind.split("_")[0]
     if mixer == "attn":
         if cfg.mla:
@@ -293,7 +324,15 @@ def _mixer_decode(cfg: ArchConfig, kind: str, p, h, pos, c, fill,
 def decode_step(cfg: ArchConfig, params: Params, tokens: jnp.ndarray,
                 cache: Dict[str, Any], fill: jnp.ndarray,
                 absorbed_mla: bool = False):
-    """tokens: (b, s_new) -> (logits (b, s_new, vocab), new cache)."""
+    """tokens: (b, s_new) -> (logits (b, s_new, vocab), new cache).
+
+    One ``lax.scan`` per segment of ``decode_segments``, its body one
+    period of layers unrolled, so no branch over kinds. The scans only
+    read the cache stacks; each layer's new entries leave its scan as an
+    output, and one dynamic_update_slice a leaf stores a segment's entries
+    in place. Written inside the scan, the stacks would be carried, and
+    the compiler lays a carried stack out for the small write: a copy of
+    every stack in and out of the loop and of every slot it reads."""
     b, s = tokens.shape
     x = embed_tokens(cfg, params["embed"], tokens)
     if cfg.mrope:
@@ -301,46 +340,53 @@ def decode_step(cfg: ArchConfig, params: Params, tokens: jnp.ndarray,
         pos = jnp.broadcast_to(pos1[None], (3, b, s))
     else:
         pos = jnp.broadcast_to(fill + jnp.arange(s)[None], (b, s))
-    sched, kinds, idx_in_kind = layer_schedule(cfg)
     layers = params["layers"]
+    cache = dict(cache)
+    load = cache.get("moe_load")        # None without MoE layers
 
-    def apply_one(kind, idx, x, caches):
+    def apply_one(kind, idx, x, load):
         p = _index_tree(layers[kind], idx)
-        c = _index_tree(caches[kind], idx)
         h = apply_norm(cfg, p["norm1"], x)
-        o, new_c = _mixer_decode(cfg, kind, p, h, pos, c, fill, absorbed_mla)
+        o, new = _mixer_decode(cfg, kind, p, h, pos,
+                               _index_tree(cache[kind], idx), fill,
+                               absorbed_mla)
         x = x + o
         ffn = kind.split("_")[1]
         if ffn != "none":
             h = apply_norm(cfg, p["norm2"], x)
             if ffn == "moe":
-                o, _, load = moe_mod.apply_moe(cfg, p["ffn"], h)
+                o, _, n = moe_mod.apply_moe(cfg, p["ffn"], h)
                 x = x + o
+                load = load + n
             else:
                 x = apply_mlp(cfg, p["ffn"], h, residual=x)
-        caches = dict(caches)
-        caches[kind] = _update_tree(caches[kind], new_c, idx)
-        if ffn == "moe":
-            caches["moe_load"] = caches["moe_load"] + load
-        return x, caches
+        return x, new, load
 
-    if cfg.unroll:
-        for i, kind in enumerate(sched):
-            x, cache = apply_one(kind, idx_in_kind[i], x, cache)
-    else:
-        kind_ids = jnp.asarray([kinds.index(k) for k in sched], jnp.int32)
-        idxs = jnp.asarray(idx_in_kind, jnp.int32)
-        branches = [(lambda kn: lambda x, cc, i: apply_one(kn, i, x, cc))(k)
-                    for k in kinds]
+    for period, idxs in decode_segments(cfg):
+        def body(carry, idx_row, period=period):
+            x, load = carry
+            new = []
+            for j, kind in enumerate(period):
+                x, n, load = apply_one(kind, idx_row[j], x, load)
+                new.append(n)
+            return (x, load), new
 
-        def body(carry, step):
-            x, caches = carry
-            kid, idx = step
-            x, caches = jax.lax.switch(kid, branches, x, caches, idx)
-            return (x, caches), None
+        (x, load), new = scan_or_unroll(cfg, body, (x, load), idxs)
+        for kind in dict.fromkeys(period):
+            at = [j for j, k in enumerate(period) if k == kind]
+            # (repeats, len(at), ...) -> the kind's layers in stack order
+            n = jax.tree.map(lambda *a: jnp.stack(a, 1).reshape(
+                (-1,) + a[0].shape[1:]), *[new[j] for j in at])
+            first = int(idxs[0, at[0]])
+            if kind.startswith("attn"):
+                cache[kind] = attn.write_cache(cache[kind], n, fill, first)
+            else:
+                cache[kind] = jax.tree.map(
+                    lambda full, a: jax.lax.dynamic_update_slice_in_dim(
+                        full, a.astype(full.dtype), first, 0), cache[kind], n)
 
-        (x, cache), _ = jax.lax.scan(body, (x, cache), (kind_ids, idxs))
-
+    if cfg.moe:
+        cache["moe_load"] = load
     h = apply_norm(cfg, params["final_norm"], x)
     logits = unembed(cfg, params["embed"], h)
     return logits, cache

@@ -205,6 +205,7 @@ def test_server_decode_is_jit_decode_absorbed_and_donated():
     assert "module @jit_decode " in text
     assert "tf.aliasing_output" in text or "jax.buffer_donor" in text
     assert "mla.attend/bhsr,bkr->bhsk" in text   # the absorbed form's scores
+    assert "stablehlo.case" not in text          # no branch over layer kinds
 
 
 def test_generate_counts_the_held_experts_assignments():
