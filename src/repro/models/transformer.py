@@ -1,22 +1,21 @@
 """Generic decoder-only LM covering all assigned decoder families.
 
-Layer heterogeneity (jamba's 1:7 attention:mamba interleave, periodic MoE,
-deepseek's leading dense layer) is handled in training and prefill as ONE
-``lax.scan`` over all layers whose body ``lax.switch``-es between the
-distinct layer *kinds* (attn/ssm mixer x moe/mlp/none ffn). Parameters and
-decode caches are stored per-kind (stacked over that kind's layers) and
-dynamically indexed each step. This keeps HLO size O(#kinds) AND gives
-true per-layer remat granularity — an unrolled heterogeneous period keeps
-every sublayer's working set live during its backward (measured 4x worse
-on jamba; nested jax.checkpoint inside a checkpointed scan body does not
-recover it).
+Layers come in *kinds* (attn/ssm mixer x moe/mlp/none ffn): jamba's 1:7
+attention:mamba interleave, periodic MoE, deepseek's leading dense layer.
+Parameters and caches are stored per kind, stacked over that kind's
+layers. One function, ``_layer``, applies a layer of any kind; training,
+prefill and decode differ only in the mixer they hand it.
 
-Decode takes no switch: a branch returns every kind's cache, so each layer
-would copy the stacks of the kinds it does not own. ``decode_step`` scans
-each segment of ``decode_segments`` (the leading dense layers, then the
-repeating rest) with one period of layers unrolled in the body. The scans
-only read the caches; after each, its layers' new positions are written
-into their kinds' stacks in place.
+Serving walks ``layer_segments`` (the leading dense layers, then the
+repeating rest): one ``lax.scan`` a segment, one period of kinds unrolled
+in its body, so no branch over kinds. A branch returns every kind's cache,
+so each layer would copy the stacks of the kinds it does not own. Prefill
+writes each layer's cache entry into its kind's stack inside the scan;
+decode's scans only read the caches, and each segment's new positions are
+written in place after it.
+
+Training scans one ``lax.switch`` over the kinds instead (``backbone``),
+for the memory of its backward pass.
 
 Entry points: init / loss / prefill / decode_step — see ``api.py``.
 """
@@ -81,6 +80,26 @@ def layer_schedule(cfg: ArchConfig):
     return sched, kinds, idx_in_kind
 
 
+def layer_segments(cfg: ArchConfig):
+    """The layer schedule as the runs prefill and decode scan: the leading
+    ``first_dense_layers``, then the rest, each split into repeats of its
+    shortest period of kinds. Returns [(period's kinds, idx_in_kind of
+    shape (repeats, len(period)))]; each kind's layers in a run are
+    consecutive in its stack."""
+    sched, _, idx_in_kind = layer_schedule(cfg)
+    fd = cfg.first_dense_layers
+    segments = []
+    for lo, hi in ((0, fd), (fd, cfg.n_layers)):
+        run = sched[lo:hi]
+        if not run:
+            continue
+        p = next(p for p in range(1, len(run) + 1)
+                 if run == run[:p] * (len(run) // p))
+        segments.append((run[:p], np.asarray(idx_in_kind[lo:hi],
+                                             np.int32).reshape(-1, p)))
+    return segments
+
+
 # ----------------------------------------------------------------------
 # Parameters
 # ----------------------------------------------------------------------
@@ -133,63 +152,101 @@ def _update_tree(tree: Params, new: Params, idx) -> Params:
 
 
 # ----------------------------------------------------------------------
-# Forward (training)
+# One layer
 # ----------------------------------------------------------------------
-def _apply_kind(cfg: ArchConfig, kind: str, p: Params, x, pos, aux):
-    mixer, ffn = kind.split("_")
-    h = apply_norm(cfg, p["norm1"], x)
-    if mixer == "attn":
-        if cfg.mla:
-            o, _ = attn.mla_forward(cfg, p["mixer"], h, pos)
-        else:
-            o, _ = attn.gqa_forward(cfg, p["mixer"], h, pos)
-    else:
-        o = ssm_mod.ssm_forward(cfg, p["mixer"], h)
+def _layer(cfg: ArchConfig, kind: str, p: Params, x, mixer, aux, load):
+    """One layer of ``kind``: norm, ``mixer(h) -> (out, cache entry)``,
+    residual, norm, the kind's FFN. Returns (x, entry, aux, load); a MoE
+    layer adds its balance loss to ``aux`` and its held experts'
+    assignment counts to ``load`` (left None where there is no count)."""
+    ffn = kind.split("_")[1]
+    o, entry = mixer(apply_norm(cfg, p["norm1"], x))
     x = x + o
     if ffn == "none":
-        return x, aux
+        return x, entry, aux, load
     h = apply_norm(cfg, p["norm2"], x)
-    if ffn == "moe":
-        o, a, _ = moe_mod.apply_moe(cfg, p["ffn"], h)
-        aux = aux + a
-        return x + o, aux
-    # residual add fused into the MLP's second-GEMM store epilogue
-    return apply_mlp(cfg, p["ffn"], h, residual=x), aux
+    if ffn == "mlp":
+        # residual add fused into the MLP's second-GEMM store epilogue
+        return apply_mlp(cfg, p["ffn"], h, residual=x), entry, aux, load
+    o, a, n = moe_mod.apply_moe(cfg, p["ffn"], h)
+    return x + o, entry, aux + a, None if load is None else load + n
 
 
+def _mixer_forward(cfg: ArchConfig, kind: str, p, h, pos):
+    """Full-sequence mixer for training; it keeps no cache entry."""
+    if kind.startswith("attn"):
+        fwd = attn.mla_forward if cfg.mla else attn.gqa_forward
+        return fwd(cfg, p["mixer"], h, pos)[0], None
+    return ssm_mod.ssm_forward(cfg, p["mixer"], h), None
+
+
+def _mixer_prefill(cfg: ArchConfig, kind: str, p, h, pos, cache_len: int):
+    """Full-sequence mixer that also returns this layer's cache entry."""
+    if kind.startswith("attn"):
+        if cfg.mla:
+            o, (c_kv, k_rope) = attn.mla_forward(cfg, p["mixer"], h, pos)
+            c = {"c_kv": _pad_seq(c_kv, cache_len, 1),
+                 "k_rope": _pad_seq(k_rope, cache_len, 2)}
+        else:
+            o, (k, v) = attn.gqa_forward(cfg, p["mixer"], h, pos)
+            c = {"k": _pad_seq(k, cache_len, 2),
+                 "v": _pad_seq(v, cache_len, 2)}
+        return o, c
+    return ssm_mod.ssm_forward(cfg, p["mixer"], h, return_state=True)
+
+
+def _mixer_decode(cfg: ArchConfig, kind: str, p, h, pos, c, fill,
+                  absorbed_mla: bool):
+    """(out, this step's entries): new positions for attention, whole
+    states for a state-space layer (they have no sequence axis)."""
+    if kind.startswith("attn"):
+        if cfg.mla:
+            return attn.mla_decode(cfg, p["mixer"], h, pos, c, fill,
+                                   absorbed=absorbed_mla)
+        return attn.gqa_decode(cfg, p["mixer"], h, pos, c, fill)
+    return ssm_mod.ssm_decode(cfg, p["mixer"], h, c)
+
+
+# ----------------------------------------------------------------------
+# Forward (training)
+# ----------------------------------------------------------------------
 def backbone(cfg: ArchConfig, params: Params, x: jnp.ndarray,
              pos) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    sp = sp_constrain if cfg.sp_residual else (lambda t: t)
     """Embedded inputs -> final hidden states. Returns (h, moe_aux_loss).
 
     Homogeneous stacks (9 of 10 assigned archs): one ``lax.scan`` over the
     stacked layer params — the memory-optimal structure (XLA's backward
     keeps one scan body's working set live).
 
-    Heterogeneous stacks (jamba): one scan whose body ``lax.switch``-es over
-    the layer kinds. Measured on this backend, a single multi-branch region
-    costs the SUM of its branches' working sets but every alternative
-    (unrolled periods, segmented scans + singleton layers) costs strictly
-    more — see EXPERIMENTS.md §Perf for the measurements. The remaining fit
-    lever is gradient accumulation (cfg.grad_accum), which divides all
-    activation transients.
+    Heterogeneous stacks (jamba, deepseek's dense first layer): one scan
+    whose body ``lax.switch``-es over the layer kinds, not serving's walk
+    of ``layer_segments``. Compiled ``jax.grad`` of the loss on the CPU
+    (reduced configs, batch 4, ``grad_accum`` 1; temporaries of the switch
+    scan -> a segment walk with each layer rematerialised, its weights
+    indexed inside the checkpoint): jamba 107.8 -> 326.7 MB at sequence 256
+    and 539.8 -> 1366.9 MB at 1024; deepseek 61.5 -> 79.9 MB and 431.8 ->
+    516.9 MB. The remaining fit lever is gradient accumulation
+    (cfg.grad_accum), which divides all activation transients.
     """
+    sp = sp_constrain if cfg.sp_residual else (lambda t: t)
     sched, kinds, idx_in_kind = layer_schedule(cfg)
     layers = params["layers"]
+
+    def apply(kind, p, x, aux):
+        x, _, aux, _ = _layer(
+            cfg, kind, p, x, lambda h: _mixer_forward(cfg, kind, p, h, pos),
+            aux, None)
+        return x, aux
 
     if cfg.unroll:
         aux = jnp.float32(0.0)
         for i, kind in enumerate(sched):
             p = _index_tree(layers[kind], idx_in_kind[i])
-            if cfg.remat != "none":
-                # keep remat (with the production policy) in the unrolled
-                # delta-method variant so measured flops/bytes include the
-                # production recompute behaviour
-                f = remat_wrap(cfg, lambda xx, aa, pp, kk=kind: _apply_kind(
-                    cfg, kk, pp, xx, pos, aa))
-                x, aux = f(x, aux, p)
-            else:
-                x, aux = _apply_kind(cfg, kind, p, x, pos, aux)
+            # remat (with the production policy) kept in the unrolled
+            # delta-method variant so measured flops/bytes include the
+            # production recompute behaviour
+            x, aux = remat_wrap(cfg, functools.partial(apply, kind))(
+                p, x, aux)
         return apply_norm(cfg, params["final_norm"], x), aux
 
     if len(kinds) == 1:
@@ -198,7 +255,7 @@ def backbone(cfg: ArchConfig, params: Params, x: jnp.ndarray,
         def body(carry, p):
             x, aux = carry
             x = sp(x)                  # SP: carry sharded (data, model, -)
-            x, aux = _apply_kind(cfg, kind, p, x, pos, aux)
+            x, aux = apply(kind, p, x, aux)
             return (sp(x), aux), None
 
         body = remat_wrap(cfg, body)
@@ -208,13 +265,10 @@ def backbone(cfg: ArchConfig, params: Params, x: jnp.ndarray,
     kind_ids = jnp.asarray([kinds.index(k) for k in sched], jnp.int32)
     idxs = jnp.asarray(idx_in_kind, jnp.int32)
 
-    def branch(kind):
-        def br(x, aux, idx):
-            p = _index_tree(layers[kind], idx)
-            return _apply_kind(cfg, kind, p, x, pos, aux)
-        return br
+    def branch(kind, x, aux, idx):
+        return apply(kind, _index_tree(layers[kind], idx), x, aux)
 
-    branches = [branch(k) for k in kinds]
+    branches = [functools.partial(branch, k) for k in kinds]
 
     def body(carry, step):
         x, aux = carry
@@ -288,37 +342,24 @@ def init_cache(cfg: ArchConfig, batch: int, seq: int,
     return caches
 
 
-def decode_segments(cfg: ArchConfig):
-    """The layer schedule as the runs ``decode_step`` scans: the leading
-    ``first_dense_layers``, then the rest, each split into repeats of its
-    shortest period of kinds. Returns [(period's kinds, idx_in_kind of
-    shape (repeats, len(period)))]; each kind's layers in a run are
-    consecutive in its stack."""
-    sched, _, idx_in_kind = layer_schedule(cfg)
-    fd = cfg.first_dense_layers
+def _walk_segments(cfg: ArchConfig, layer_fn, carry):
+    """Every layer in schedule order: per segment of ``layer_segments``, one
+    ``scan_or_unroll`` over its rows of ``idx_in_kind``, one period of
+    layers unrolled in the body. ``layer_fn(kind, idx, carry) -> (carry,
+    out)``. Returns (carry, [(period, idxs, outs)]), each segment's outs
+    listed by position in the period and stacked over its repeats."""
     segments = []
-    for lo, hi in ((0, fd), (fd, cfg.n_layers)):
-        run = sched[lo:hi]
-        if not run:
-            continue
-        p = next(p for p in range(1, len(run) + 1)
-                 if run == run[:p] * (len(run) // p))
-        segments.append((run[:p], np.asarray(idx_in_kind[lo:hi],
-                                             np.int32).reshape(-1, p)))
-    return segments
+    for period, idxs in layer_segments(cfg):
+        def body(carry, row, period=period):
+            outs = []
+            for j, kind in enumerate(period):
+                carry, out = layer_fn(kind, row[j], carry)
+                outs.append(out)
+            return carry, outs
 
-
-def _mixer_decode(cfg: ArchConfig, kind: str, p, h, pos, c, fill,
-                  absorbed_mla: bool):
-    """(out, this step's entries): new positions for attention, whole
-    states for a state-space layer (they have no sequence axis)."""
-    mixer = kind.split("_")[0]
-    if mixer == "attn":
-        if cfg.mla:
-            return attn.mla_decode(cfg, p["mixer"], h, pos, c, fill,
-                                   absorbed=absorbed_mla)
-        return attn.gqa_decode(cfg, p["mixer"], h, pos, c, fill)
-    return ssm_mod.ssm_decode(cfg, p["mixer"], h, c)
+        carry, outs = scan_or_unroll(cfg, body, carry, idxs)
+        segments.append((period, idxs, outs))
+    return carry, segments
 
 
 def decode_step(cfg: ArchConfig, params: Params, tokens: jnp.ndarray,
@@ -326,52 +367,31 @@ def decode_step(cfg: ArchConfig, params: Params, tokens: jnp.ndarray,
                 absorbed_mla: bool = False):
     """tokens: (b, s_new) -> (logits (b, s_new, vocab), new cache).
 
-    One ``lax.scan`` per segment of ``decode_segments``, its body one
-    period of layers unrolled, so no branch over kinds. The scans only
-    read the cache stacks; each layer's new entries leave its scan as an
-    output, and one dynamic_update_slice a leaf stores a segment's entries
-    in place. Written inside the scan, the stacks would be carried, and
-    the compiler lays a carried stack out for the small write: a copy of
-    every stack in and out of the loop and of every slot it reads."""
-    b, s = tokens.shape
+    The walk of ``layer_segments`` only reads the cache stacks; each
+    layer's new entries leave its scan as an output, and one
+    dynamic_update_slice a leaf stores a segment's entries in place.
+    Written inside the scan, the stacks would be carried, and the compiler
+    lays a carried stack out for the small write: a copy of every stack in
+    and out of the loop and of every slot it reads."""
     x = embed_tokens(cfg, params["embed"], tokens)
-    if cfg.mrope:
-        pos1 = fill + jnp.arange(s)[None]
-        pos = jnp.broadcast_to(pos1[None], (3, b, s))
-    else:
-        pos = jnp.broadcast_to(fill + jnp.arange(s)[None], (b, s))
+    pos = positions(cfg, {"tokens": tokens}) + fill
     layers = params["layers"]
     cache = dict(cache)
-    load = cache.get("moe_load")        # None without MoE layers
 
-    def apply_one(kind, idx, x, load):
+    def layer(kind, idx, carry):
+        x, load = carry
         p = _index_tree(layers[kind], idx)
-        h = apply_norm(cfg, p["norm1"], x)
-        o, new = _mixer_decode(cfg, kind, p, h, pos,
-                               _index_tree(cache[kind], idx), fill,
-                               absorbed_mla)
-        x = x + o
-        ffn = kind.split("_")[1]
-        if ffn != "none":
-            h = apply_norm(cfg, p["norm2"], x)
-            if ffn == "moe":
-                o, _, n = moe_mod.apply_moe(cfg, p["ffn"], h)
-                x = x + o
-                load = load + n
-            else:
-                x = apply_mlp(cfg, p["ffn"], h, residual=x)
-        return x, new, load
+        c = _index_tree(cache[kind], idx)
+        x, new, _, load = _layer(
+            cfg, kind, p, x,
+            lambda h: _mixer_decode(cfg, kind, p, h, pos, c, fill,
+                                    absorbed_mla), 0.0, load)
+        return (x, load), new
 
-    for period, idxs in decode_segments(cfg):
-        def body(carry, idx_row, period=period):
-            x, load = carry
-            new = []
-            for j, kind in enumerate(period):
-                x, n, load = apply_one(kind, idx_row[j], x, load)
-                new.append(n)
-            return (x, load), new
-
-        (x, load), new = scan_or_unroll(cfg, body, (x, load), idxs)
+    # moe_load is None without MoE layers
+    (x, load), segments = _walk_segments(cfg, layer,
+                                         (x, cache.get("moe_load")))
+    for period, idxs, new in segments:
         for kind in dict.fromkeys(period):
             at = [j for j, k in enumerate(period) if k == kind]
             # (repeats, len(at), ...) -> the kind's layers in stack order
@@ -392,44 +412,26 @@ def decode_step(cfg: ArchConfig, params: Params, tokens: jnp.ndarray,
     return logits, cache
 
 
-def _mixer_prefill(cfg: ArchConfig, kind: str, p, h, pos, cache_len: int):
-    """Full-sequence mixer that also returns this layer's cache entry."""
-    mixer = kind.split("_")[0]
-    if mixer == "attn":
-        if cfg.mla:
-            o, (c_kv, k_rope) = attn.mla_forward(cfg, p["mixer"], h, pos)
-            c = {"c_kv": _pad_seq(c_kv, cache_len, 1),
-                 "k_rope": _pad_seq(k_rope, cache_len, 2)}
-        else:
-            o, (k, v) = attn.gqa_forward(cfg, p["mixer"], h, pos)
-            c = {"k": _pad_seq(k, cache_len, 2),
-                 "v": _pad_seq(v, cache_len, 2)}
-        return o, c
-    return ssm_mod.ssm_forward(cfg, p["mixer"], h, return_state=True)
-
-
 def prefill(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
             cache_len: Optional[int] = None):
     """Full-sequence forward that also fills the cache.
 
-    Returns (last-position logits, cache, fill). With
-    ``cfg.prefill_microbatch > 1`` the request batch is processed in
-    sequential chunks (serving-style chunked prefill) — divides peak
-    activation memory by the chunk count at unchanged total compute."""
-    mb = max(1, cfg.prefill_microbatch)
+    Returns (last-position logits, cache, fill). The request batch is
+    processed in ``cfg.prefill_microbatch`` sequential chunks where that
+    divides it (serving-style chunked prefill: peak activation memory over
+    the chunk count at unchanged total compute), else as one chunk."""
     b, s = batch["tokens"].shape
-    if mb > 1 and b % mb == 0:
-        logits, cache = _prefill_chunked(cfg, params, batch, cache_len or s,
-                                         mb)
-        return logits, cache, s
-    return _prefill_impl(cfg, params, batch, cache_len)
+    mb = max(1, cfg.prefill_microbatch)
+    logits, cache = _prefill_chunked(cfg, params, batch, cache_len or s,
+                                     mb if b % mb == 0 else 1)
+    return logits, cache, s
 
 
 @functools.partial(jax.jit, static_argnums=(0, 3, 4))
 def _prefill_chunked(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
                      cache_len: int, mb: int):
-    """``prefill`` of ``mb`` chunks of the batch, one after another, each
-    chunk's cache written into one whole-batch cache in place.
+    """``_prefill_impl`` of ``mb`` chunks of the batch, one after another,
+    each chunk's cache written into one whole-batch cache in place.
     Returns (logits, cache)."""
     b = batch["tokens"].shape[0]
     bc = b // mb
@@ -442,7 +444,7 @@ def _prefill_chunked(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
 
     def body(cache, step):
         i, chunk = step
-        logits, part, _ = _prefill_impl(cfg, params, chunk, cache_len)
+        logits, part = _prefill_impl(cfg, params, chunk, cache_len)
         cache = dict(cache)
         for name, new in part.items():
             if name == "moe_load":
@@ -460,58 +462,34 @@ def _prefill_chunked(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
 
 
 def _prefill_impl(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
-                  cache_len: Optional[int] = None):
-    tokens = batch["tokens"]
-    b, s = tokens.shape
-    cache_len = cache_len or s
+                  cache_len: int):
+    """One chunk: the walk of ``layer_segments``, each layer writing its
+    cache entry into its own kind's stack of the carried chunk cache.
+    Returns (last-position logits, cache)."""
     x = embed_inputs(cfg, params, batch)
     pos = positions(cfg, batch)
-    sched, kinds, idx_in_kind = layer_schedule(cfg)
     layers = params["layers"]
-    caches = init_cache(cfg, b, cache_len, jnp.bfloat16)
+    sp = sp_constrain if cfg.sp_residual else (lambda t: t)
 
-    def apply_one(kind, idx, x, caches):
+    def layer(kind, idx, carry):
+        x, caches = carry
         p = _index_tree(layers[kind], idx)
-        h = apply_norm(cfg, p["norm1"], x)
-        o, new_c = _mixer_prefill(cfg, kind, p, h, pos, cache_len)
-        x = x + o
-        ffn = kind.split("_")[1]
-        if ffn != "none":
-            h = apply_norm(cfg, p["norm2"], x)
-            if ffn == "moe":
-                o, _, load = moe_mod.apply_moe(cfg, p["ffn"], h)
-                x = x + o
-            else:
-                x = apply_mlp(cfg, p["ffn"], h, residual=x)
+        x, entry, _, load = _layer(
+            cfg, kind, p, sp(x),
+            lambda h: _mixer_prefill(cfg, kind, p, h, pos, cache_len),
+            0.0, caches.get("moe_load"))
         caches = dict(caches)
-        caches[kind] = _update_tree(caches[kind], new_c, idx)
-        if ffn == "moe":
-            caches["moe_load"] = caches["moe_load"] + load
-        return x, caches
+        caches[kind] = _update_tree(caches[kind], entry, idx)
+        if cfg.moe:
+            caches["moe_load"] = load
+        return (sp(x), caches), None
 
-    if cfg.unroll:
-        for i, kind in enumerate(sched):
-            x, caches = apply_one(kind, idx_in_kind[i], x, caches)
-    else:
-        kind_ids = jnp.asarray([kinds.index(k) for k in sched], jnp.int32)
-        idxs = jnp.asarray(idx_in_kind, jnp.int32)
-        branches = [(lambda kn: lambda x, cc, i: apply_one(kn, i, x, cc))(k)
-                    for k in kinds]
-
-        sp = sp_constrain if cfg.sp_residual else (lambda t: t)
-
-        def body(carry, step):
-            x, caches = carry
-            kid, idx = step
-            x = sp(x)
-            x, caches = jax.lax.switch(kid, branches, x, caches, idx)
-            return (sp(x), caches), None
-
-        (x, caches), _ = jax.lax.scan(body, (x, caches), (kind_ids, idxs))
-
+    b = batch["tokens"].shape[0]
+    (x, caches), _ = _walk_segments(cfg, layer,
+                                    (x, init_cache(cfg, b, cache_len)))
     h = apply_norm(cfg, params["final_norm"], x)
     logits = unembed(cfg, params["embed"], h[:, -1:])
-    return logits[:, 0], caches, s
+    return logits[:, 0], caches
 
 
 def _pad_seq(x: jnp.ndarray, to: int, axis: int) -> jnp.ndarray:

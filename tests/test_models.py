@@ -202,14 +202,31 @@ def test_scanned_decode_matches_unrolled(arch, absorbed):
                                rtol=2e-2, atol=2e-2)
 
 
-@pytest.mark.parametrize("arch", configs.ARCHS)
-def test_server_decode_takes_no_branch(arch):
+def _no_branch_cases():
+    """Every registered config's decode (ids as before: the arch), and the
+    prefill of each decoder-only one."""
+    return ([pytest.param("decode", a, id=a) for a in configs.ARCHS]
+            + [pytest.param("prefill", a, id=f"{a}-prefill")
+               for a in configs.ARCHS
+               if not configs.get_reduced(a).encoder_decoder])
+
+
+@pytest.mark.parametrize("phase,arch", _no_branch_cases())
+def test_server_decode_takes_no_branch(phase, arch):
     """The served decode step is ``jit_decode`` with its cache donated and
-    no conditional: a branch over layer kinds returns every kind's cache,
-    which makes each layer copy the stacks it does not own."""
+    no conditional, and the jitted prefill has none either: a branch over
+    layer kinds returns every kind's cache, which makes each layer copy
+    the stacks it does not own."""
     cfg = configs.get_reduced(arch)
     model = Model(cfg)
     params = jax.eval_shape(lambda: model._mod.init_params(cfg, 0))
+    if phase == "prefill":
+        batch = jax.eval_shape(lambda: _batch(cfg))
+        text = jax.jit(model.prefill, static_argnums=2).lower(
+            params, batch, 40).as_text()
+        assert "_prefill_chunked" in text
+        assert "stablehlo.case" not in text
+        return
     srv = Server(cfg, params, ServeConfig(max_seq=16, max_new_tokens=2,
                                           eos_token=-1))
     cache = jax.eval_shape(lambda: model.init_cache(2, 16))
@@ -219,3 +236,60 @@ def test_server_decode_takes_no_branch(arch):
     assert "module @jit_decode " in text
     assert "tf.aliasing_output" in text or "jax.buffer_donor" in text
     assert "stablehlo.case" not in text
+
+
+@pytest.mark.parametrize("arch", [
+    "deepseek_v2_lite_16b",            # dense layer, then MoE layers
+    "jamba_v01_52b",                   # interleaved period of 8
+    "qwen2.5",                         # one kind
+])
+def test_scanned_prefill_matches_unrolled(arch):
+    """The prefill's walk of the layer segments, scanned and with every
+    layer unrolled: the same last-position logits, and caches equal to
+    one bf16 step (or 1e-5 near zero). The compiler fuses a scanned and an
+    unrolled layer apart, so their float32 work can part in its last bits
+    and a stored value round the other way."""
+    cfg = (_qwen25_reduced() if arch == "qwen2.5"
+           else configs.get_reduced(arch)).scaled(
+        compute_dtype="float32", param_dtype="float32")
+    model, unrolled = Model(cfg), Model(cfg.scaled(unroll=True))
+    params = model.init(0)
+    batch = {"tokens": jnp.asarray(np.random.default_rng(6).integers(
+        0, cfg.vocab, (4, 12)), jnp.int32)}
+    l_scan, c_scan, f_scan = model.prefill(params, batch, cache_len=16)
+    l_flat, c_flat, f_flat = unrolled.prefill(params, batch, cache_len=16)
+    assert f_scan == f_flat == 12
+    np.testing.assert_allclose(np.asarray(l_scan), np.asarray(l_flat),
+                               rtol=2e-2, atol=2e-2)
+    assert jax.tree.structure(c_scan) == jax.tree.structure(c_flat)
+    for a, b in zip(jax.tree.leaves(c_scan), jax.tree.leaves(c_flat)):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=2 ** -7, atol=1e-5)
+
+
+def test_prefill_microbatch_not_dividing_runs_one_chunk(monkeypatch):
+    """A ``prefill_microbatch`` that does not divide the batch prefills it
+    as one chunk, the same program as ``prefill_microbatch=1``."""
+    from repro.models import transformer
+    cfg = configs.get_reduced("phi3.5-moe-42b-a6.6b").scaled(
+        compute_dtype="float32", param_dtype="float32")
+    chunked, whole = (Model(cfg.scaled(prefill_microbatch=4)),
+                      Model(cfg.scaled(prefill_microbatch=1)))
+    params = whole.init(0)
+    batch = {"tokens": jnp.asarray(np.random.default_rng(7).integers(
+        0, cfg.vocab, (6, 8)), jnp.int32)}
+    chunks = []
+    run = transformer._prefill_chunked
+
+    def spy(cfg, params, batch, cache_len, mb):
+        chunks.append(mb)
+        return run(cfg, params, batch, cache_len, mb)
+
+    monkeypatch.setattr(transformer, "_prefill_chunked", spy)
+    l1, c1, f1 = chunked.prefill(params, batch, cache_len=12)
+    l2, c2, f2 = whole.prefill(params, batch, cache_len=12)
+    assert chunks == [1, 1] and f1 == f2 == 8
+    np.testing.assert_array_equal(np.asarray(l1), np.asarray(l2))
+    for a, b in zip(jax.tree.leaves(c1), jax.tree.leaves(c2)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
