@@ -282,8 +282,7 @@ def phase_four_chips(chk, seed):
     from repro.models import Model
     from repro.models.common import set_activation_sharding
     from repro.optim import AdamWConfig, init_opt_state
-    from repro.runtime.serve import (_ARGMAX_PROGRAMS,
-                                     greedy_argmax_multistream)
+    from repro.runtime.serve import _ARGMAX_PROGRAMS, greedy_argmax_program
     from repro.runtime.train import make_train_step, state_shardings
 
     rng = np.random.default_rng(seed)
@@ -319,7 +318,7 @@ def phase_four_chips(chk, seed):
     # chips (n_clusters defaults to the device count): where its operands
     # and results land, and that it still equals numpy's argmax.
     logits = jnp.asarray(uniform(rng, (8, 49155)))
-    ids = greedy_argmax_multistream(logits)
+    ids = greedy_argmax_program(logits)
     st = _ARGMAX_PROGRAMS[(8, 49155)][1].stats["scheduler"]
     log(phase=chk.phase, program="serve_sampler_b8", mode_used=st["mode_used"],
         n_devices_used=st.get("n_devices_used"),

@@ -2,22 +2,26 @@
 
 Continuous-batching-lite: a fixed decode batch of slots; finished requests
 (EOS or max-len) are replaced by queued requests whose prompts are
-prefilled into the freed slot. Sampling uses the NTX ARGMAX command
-(greedy) or temperature sampling. Works for all decoder archs, including
+prefilled into the freed slot. Works for all decoder archs, including
 SSM/hybrid state caches.
 
-Both samplers are descriptor :class:`~repro.core.program.Program`\\ s run
-through the policy-driven :class:`~repro.core.executor.Executor`. Greedy:
-each request's ARGMAX over its logits row is an independent sub-stream
-(disjoint buffers), so the batch partitions request-per-cluster and
-executes concurrently on the mesh — the serving-side use of the paper's
-independent per-cluster streams. Temperature: sampling prep is the
-streaming chain scale-by-temperature (AXPY ``logits/T + gumbel`` — the
-Gumbel-max identity makes the added noise an exact draw from the softmax
+Greedy sampling is one jitted ARGMAX over the rows of the logits on the
+device (``greedy_argmax_multistream``); its ids feed the next decode step
+where they lie, and the host reads each step's ids while the next step
+runs, one step behind. Temperature sampling is a descriptor
+:class:`~repro.core.program.Program` run through the policy-driven
+:class:`~repro.core.executor.Executor`: sampling prep is the streaming
+chain scale-by-temperature (AXPY ``logits/T + gumbel`` — the Gumbel-max
+identity makes the added noise an exact draw from the softmax
 distribution) -> optional THRESH prune -> ARGMAX chain-reduce tail, one
 fused pass per request, regression-tested against ``jax.nn.softmax``
-sampling. No hand-computed base addresses: the program's allocator owns
-the layout.
+sampling. The greedy descriptor programs stay as library functions
+(``greedy_argmax_program``, ``greedy_argmax_pipelined``): each request's
+ARGMAX over its logits row is an independent sub-stream (disjoint
+buffers), so the batch partitions request-per-cluster and executes
+concurrently on the mesh — the serving-side use of the paper's
+independent per-cluster streams. No hand-computed base addresses: the
+program's allocator owns the layout.
 """
 from __future__ import annotations
 
@@ -43,8 +47,6 @@ class ServeConfig:
     eos_token: int = 1
     temperature: float = 0.0
     seed: int = 0
-    multistream: bool = True        # sampling programs via the cluster mesh
-    pipeline: bool = True           # prefill sampling via the stage pipeline
     #: optional THRESH prune in the temperature-sampling chain: perturbed
     #: scaled logits at or below the floor drop to 0 before the ARGMAX
     #: tail (epsilon-style pruning in logit space; None disables the
@@ -103,18 +105,29 @@ def _read_slots(res, slots) -> np.ndarray:
         return res.take(slots).astype(np.int64)
 
 
-def greedy_argmax_multistream(logits) -> np.ndarray:
+def greedy_argmax_program(logits) -> np.ndarray:
     """Greedy sampling as a multi-cluster descriptor program.
 
     One ARGMAX command per request row — independent uniform sub-streams
     the scheduler can stack (vmap/shard_map lanes), cached per batch
     shape. Ties resolve to the first maximum, matching ``np.argmax``.
+    Returns host int64 ids, the slots read by ``ProgramResult.take``.
     """
     logits = jnp.asarray(logits, jnp.float32)
     b, vocab = logits.shape
     return _run_sampler(
         _sampler_entry(_ARGMAX_PROGRAMS, b, vocab, staged=False,
                        policy="multistream"), logits)
+
+
+@jax.jit
+def greedy_argmax_multistream(logits) -> jax.Array:
+    """Greedy sampling on the device: the ARGMAX over each row of a
+    ``(b, vocab)`` array as one jitted program, returning device int32
+    ids. Ties resolve to the first maximum, as the NTX ARGMAX command and
+    ``np.argmax`` do. ``Server`` looks it up by this name when it samples,
+    and takes numpy ids from a stand-in as well."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 def greedy_argmax_pipelined(logits) -> np.ndarray:
@@ -204,52 +217,70 @@ class Server:
     def __init__(self, cfg: ArchConfig, params, scfg: ServeConfig):
         self.cfg, self.params, self.scfg = cfg, params, scfg
         self.model = Model(cfg)
-        # the cache is donated: each step updates it in place
-        self._decode = jax.jit(self.model.decode,
-                               static_argnames=("absorbed_mla",),
+        model, vocab = self.model, cfg.vocab
+
+        def decode(params, ids, cache, fill, absorbed_mla=False):
+            """One decode step on the ``(b,)`` sampled ids: the logits over
+            the vocabulary (the padded ids are never sampled; see
+            ArchConfig.padded_vocab), the cache, and ``fill + 1``, so
+            nothing of a step runs outside the program."""
+            logits, cache = model.decode(params, ids[:, None], cache, fill,
+                                         absorbed_mla=absorbed_mla)
+            return logits[:, -1, :vocab], cache, fill + 1
+
+        # compiled as ``jit_decode``; the cache is donated: each step
+        # updates it in place
+        self._decode = jax.jit(decode, static_argnames=("absorbed_mla",),
                                donate_argnames=("cache",))
         install_gc_span()
 
-    def _sample(self, logits: jnp.ndarray, rng,
-                prefill: bool = False) -> np.ndarray:
-        if self.scfg.temperature <= 0 and prefill and self.scfg.pipeline:
-            # prefill: the logits row is handed off head-cluster ->
-            # sampler-cluster through the stage pipeline
-            return greedy_argmax_pipelined(logits)
-        if self.scfg.temperature <= 0 and self.scfg.multistream:
-            return greedy_argmax_multistream(logits)
-        if self.scfg.temperature > 0 and self.scfg.multistream:
+    def _sample(self, logits, rng) -> jax.Array:
+        """The next ids of a ``(b, vocab)`` array of logits, as device
+        int32: greedy ones are the device's own, others are uploaded.
+        ``greedy_argmax_multistream`` is looked up when called, so a
+        stand-in that returns numpy ids drives the loop as well."""
+        scfg = self.scfg
+        if scfg.temperature <= 0:
+            ids = greedy_argmax_multistream(logits)
+        else:
             # sampling prep runs as a descriptor program on the mesh;
             # the host only draws the Gumbel noise
-            g = rng.gumbel(size=np.asarray(logits).shape)
-            return temperature_sample_multistream(
-                logits, self.scfg.temperature, g, self.scfg.min_logit)
-        logits = np.asarray(logits, np.float32)
-        if self.scfg.temperature <= 0:
-            return logits.argmax(-1)
-        z = logits / self.scfg.temperature
-        z = z - z.max(-1, keepdims=True)
-        p = np.exp(z)
-        p /= p.sum(-1, keepdims=True)
-        return np.array([rng.choice(len(q), p=q) for q in p])
+            g = rng.gumbel(size=logits.shape)
+            ids = temperature_sample_multistream(
+                logits, scfg.temperature, g, scfg.min_logit)
+        ids = jnp.asarray(ids, jnp.int32)
+        ids.copy_to_host_async()        # read a step later: start the copy
+        return ids
 
     def generate(self, prompts: List[np.ndarray],
                  extra: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
         """Greedy/temperature generation for a batch of same-length prompts.
 
+        The host reads the ids one step behind: each iteration dispatches
+        the decode step on the device ids of the last, and its sampler,
+        and only then reads the last ids, emits them and checks EOS, so
+        one step is queued on the device while the host works. A call
+        dispatches ``max_new_tokens - 1`` decode steps (the last token
+        needs no forward); when every row has met EOS the queued step is
+        dropped.
+
         Returns the ``completions``, ``prefill_s`` (the call's start to
-        the first tokens on the host: the batch's time to first token)
-        and ``decode_tok_per_s``; MoE configs also return
-        ``moe_assigned``, the assignments routed to the held experts over
-        the call's layers and forward passes, and
-        ``moe_max_expert_load``, the most of them one held expert
-        received (counted on the device, read once at the end). Spans
-        (``repro.core.spans``): ``serve.generate`` (stats ``call``,
-        ``batch``, ``prompt_len``, and the two MoE counts) holds
-        ``serve.prefill`` and one ``serve.step`` (``step``,
-        ``active``: rows not done) per loop iteration; each holds a
-        ``serve.forward`` and a ``serve.sample`` (``phase`` prefill or
-        decode), and a step first runs ``serve.emit``, the token
+        the first tokens on the host: the batch's time to first token),
+        ``decode_tok_per_s``, ``steps_ahead`` (decode steps dispatched
+        before the previous ids were read) and ``steps_discarded`` (queued
+        steps dropped at EOS); MoE configs also return ``moe_assigned``,
+        the assignments routed to the held experts over the call's layers
+        and forward passes, and ``moe_max_expert_load``, the most of them
+        one held expert received (counted on the device, read once at the
+        end). Spans (``repro.core.spans``): ``serve.generate`` (stats
+        ``call``, ``batch``, ``prompt_len``, ``steps_ahead``,
+        ``steps_discarded`` and the two MoE counts) holds
+        ``serve.prefill`` and one ``serve.step`` (``step``, ``active``:
+        rows not done) per token. The prefill holds a ``serve.forward``
+        and a ``serve.sample`` (``phase`` prefill); a step holds the next
+        step's ``serve.forward`` and ``serve.sample`` (``phase`` decode;
+        none in the last step), then ``serve.readback`` (``bytes``), the
+        host's wait for this step's ids, and ``serve.emit``, the token
         bookkeeping.
         """
         scfg = self.scfg
@@ -257,9 +288,8 @@ class Server:
         b = len(prompts)
         plen = len(prompts[0])
         assert all(len(p) == plen for p in prompts), "same-length prompts"
-        # the unembedding is padded past the vocabulary (see
-        # ArchConfig.padded_vocab); padded ids are never sampled
         vocab = self.cfg.vocab
+        n = scfg.max_new_tokens
         t0 = time.perf_counter()
         with span("serve.generate", call=next(_CALL_IDS), batch=b,
                   prompt_len=plen) as call:
@@ -272,15 +302,28 @@ class Server:
                     logits, cache, fill = self.model.prefill(
                         self.params, batch, cache_len=scfg.max_seq)
                 with span("serve.sample", phase="prefill"):
-                    cur = self._sample(logits[:, :vocab], rng, prefill=True)
-            t1 = time.perf_counter()
+                    ids = self._sample(logits[:, :vocab], rng)
 
             out = [[] for _ in range(b)]
             done = np.zeros(b, bool)
             fill = jnp.int32(fill)
-            steps = 0
-            for i in range(scfg.max_new_tokens):
+            ahead = discarded = 0
+            t1 = t0
+            for i in range(n):
                 with span("serve.step", step=i, active=b - int(done.sum())):
+                    nxt = None
+                    if i + 1 < n:
+                        with span("serve.forward", phase="decode"):
+                            logits, cache, fill = self._decode(
+                                self.params, ids, cache, fill,
+                                absorbed_mla=self.cfg.mla_absorb)
+                        with span("serve.sample", phase="decode"):
+                            nxt = self._sample(logits, rng)
+                        ahead += 1
+                    with span("serve.readback", bytes=4 * b):
+                        cur = np.asarray(ids)
+                    if i == 0:
+                        t1 = time.perf_counter()
                     with span("serve.emit"):
                         for r in range(b):
                             if not done[r]:
@@ -288,24 +331,18 @@ class Server:
                                 if cur[r] == scfg.eos_token:
                                     done[r] = True
                     if done.all():
+                        discarded = int(nxt is not None)
                         break
-                    with span("serve.forward", phase="decode"):
-                        logits, cache = self._decode(
-                            self.params, jnp.asarray(cur[:, None], jnp.int32),
-                            cache, fill, absorbed_mla=self.cfg.mla_absorb)
-                    fill = fill + 1
-                    with span("serve.sample", phase="decode"):
-                        cur = self._sample(logits[:, -1, :vocab], rng)
-                    steps += 1
+                    ids = nxt
             decode_s = time.perf_counter() - t1
-            result = {"completions": out,
-                      "prefill_s": t1 - t0,
-                      "decode_tok_per_s": (steps * b / decode_s)
-                      if decode_s else 0.0}
+            counts = {"steps_ahead": ahead, "steps_discarded": discarded}
             if "moe_load" in cache:
                 load = np.asarray(cache["moe_load"])
-                counts = {"moe_assigned": int(load.sum()),
-                          "moe_max_expert_load": int(load.max())}
-                result.update(counts)
-                call.set_metadata(**counts)
-        return result
+                counts.update(moe_assigned=int(load.sum()),
+                              moe_max_expert_load=int(load.max()))
+            call.set_metadata(**counts)
+        return {"completions": out,
+                "prefill_s": t1 - t0,
+                "decode_tok_per_s": ((ahead - discarded) * b / decode_s)
+                if decode_s else 0.0, **counts}
+

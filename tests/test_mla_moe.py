@@ -198,7 +198,7 @@ def test_server_decode_is_jit_decode_absorbed_and_donated():
     srv = Server(cfg, params, ServeConfig(max_seq=16, max_new_tokens=3,
                                           eos_token=-1))
     cache = model.init_cache(2, 16)
-    text = srv._decode.lower(params, jnp.zeros((2, 1), jnp.int32), cache,
+    text = srv._decode.lower(params, jnp.zeros((2,), jnp.int32), cache,
                              jnp.int32(4),
                              absorbed_mla=cfg.mla_absorb).as_text(
                                  debug_info=True)
@@ -215,15 +215,17 @@ def test_generate_counts_the_held_experts_assignments():
     b, s, new = 2, 6, 3
     out = srv.generate([np.arange(s) + 7 * r for r in range(b)])
     moe_layers = cfg.n_layers - cfg.first_dense_layers
-    # all experts held: every assignment of the prefill and of the ``new``
-    # decode steps is counted
-    assert out["moe_assigned"] == (b * s + new * b) * cfg.top_k * moe_layers
+    # all experts held: every assignment of the prefill and of the
+    # ``new - 1`` decode steps is counted (the last token needs no forward)
+    assert out["moe_assigned"] == (b * s + (new - 1) * b) * cfg.top_k \
+        * moe_layers
     assert out["moe_assigned"] / cfg.n_experts <= out[
         "moe_max_expert_load"] <= out["moe_assigned"]
     dense = configs.get_reduced("llama3-8b")
     out = Server(dense, Model(dense).init(0), ServeConfig(
         max_seq=16, max_new_tokens=2, eos_token=-1)).generate([np.arange(4)])
-    assert set(out) == {"completions", "prefill_s", "decode_tok_per_s"}
+    assert set(out) == {"completions", "prefill_s", "decode_tok_per_s",
+                        "steps_ahead", "steps_discarded"}
 
 
 def test_generate_span_carries_the_moe_counts(tmp_path):

@@ -230,7 +230,7 @@ def test_server_decode_takes_no_branch(phase, arch):
     srv = Server(cfg, params, ServeConfig(max_seq=16, max_new_tokens=2,
                                           eos_token=-1))
     cache = jax.eval_shape(lambda: model.init_cache(2, 16))
-    text = srv._decode.lower(params, jnp.zeros((2, 1), jnp.int32), cache,
+    text = srv._decode.lower(params, jnp.zeros((2,), jnp.int32), cache,
                              jnp.int32(4),
                              absorbed_mla=cfg.mla_absorb).as_text()
     assert "module @jit_decode " in text

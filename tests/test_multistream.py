@@ -337,7 +337,7 @@ def test_serve_samplers_read_only_the_slots(sampler, monkeypatch):
         want = np.argmax(np.log(np.asarray(jax.nn.softmax(
             jnp.asarray(logits) / t, axis=-1))) + g, axis=-1)
     else:
-        fn = (serve.greedy_argmax_multistream if sampler == "greedy"
+        fn = (serve.greedy_argmax_program if sampler == "greedy"
               else serve.greedy_argmax_pipelined)
         got, want = fn(logits), logits.argmax(-1)
     assert got.dtype == np.int64

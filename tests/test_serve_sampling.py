@@ -1,11 +1,19 @@
-"""Batched temperature sampling as a descriptor program (runtime/serve.py).
+"""Sampling in the serving loop (runtime/serve.py).
 
-The sampling prep chain — scale-by-temperature AXPY (+ Gumbel noise) ->
+Greedy serving samples on the device and reads each step's ids one step
+behind: its completions must equal a plain step-by-step decode with the
+host's argmax, stop at EOS, and follow a numpy stand-in for the sampler.
+Batched temperature sampling is a descriptor program: the sampling prep
+chain — scale-by-temperature AXPY (+ Gumbel noise) ->
 optional THRESH prune -> ARGMAX chain-reduce tail — must fuse into one
 pass per request, execute request-per-cluster on the mesh, and agree with
 ``jax.nn.softmax`` sampling both exactly (shared noise, Gumbel-max
 identity) and in distribution.
 """
+import dataclasses
+import functools
+import itertools
+
 import numpy as np
 import pytest
 
@@ -13,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.stream import FusedChainReduce, plan_stream
+from repro.models import Model
 from repro.runtime.serve import (ServeConfig, Server,
                                  _TEMPERATURE_PROGRAMS,
                                  temperature_sample_multistream)
@@ -126,3 +135,126 @@ def test_server_sample_routes_temperature_through_program():
     srv.scfg = ServeConfig(temperature=0.0)
     greedy = srv._sample(jnp.asarray(logits), rng)
     np.testing.assert_array_equal(greedy, logits.argmax(-1))
+
+
+# ----------------------------------------------------------------------
+# Greedy serving: device ARGMAX, ids read one step behind
+# ----------------------------------------------------------------------
+def _reduced(arch):
+    from repro import configs
+    cfg = configs.get_reduced(arch)
+    # a vocabulary short of its padding, so padded ids exist to be missed
+    return dataclasses.replace(cfg, vocab=cfg.padded_vocab - 12)
+
+
+SERVED = ["llama3-8b", "deepseek-v2-lite-16b"]     # dense GQA; MLA + MoE
+
+
+@functools.lru_cache(maxsize=None)
+def _served(arch):
+    cfg = _reduced(arch)
+    return cfg, Model(cfg).init(0)
+
+
+def _prompts(b=3, s=6, vocab=500):
+    return [(np.arange(s) * (r + 3) + 11 * r) % vocab for r in range(b)]
+
+
+def _plain_greedy(cfg, params, prompts, new, max_seq=16):
+    """``Model.decode`` step by step, each step's ids the host's
+    ``np.argmax`` over the vocabulary: (b, new) ids."""
+    model = Model(cfg)
+    tokens = jnp.asarray(np.stack(prompts), jnp.int32)
+    logits, cache, fill = model.prefill(
+        params, {"tokens": tokens, "labels": jnp.zeros_like(tokens)},
+        cache_len=max_seq)
+    step = jax.jit(model.decode, static_argnames=("absorbed_mla",))
+    cur = np.asarray(logits[:, :cfg.vocab], np.float32).argmax(-1)
+    out = [cur]
+    for i in range(new - 1):
+        logits, cache = step(params, jnp.asarray(cur[:, None], jnp.int32),
+                             cache, jnp.int32(fill + i),
+                             absorbed_mla=cfg.mla_absorb)
+        cur = np.asarray(logits[:, -1, :cfg.vocab], np.float32).argmax(-1)
+        out.append(cur)
+    return np.stack(out, 1)
+
+
+def _serve(cfg, params, prompts, new, eos=-1):
+    return Server(cfg, params, ServeConfig(max_seq=16, max_new_tokens=new,
+                                           eos_token=eos)).generate(prompts)
+
+
+@pytest.mark.parametrize("arch", SERVED)
+def test_greedy_completions_equal_a_plain_decode_loop(arch):
+    cfg, params = _served(arch)
+    prompts, new = _prompts(vocab=cfg.vocab), 5
+    out = _serve(cfg, params, prompts, new)
+    np.testing.assert_array_equal(np.asarray(out["completions"]),
+                                  _plain_greedy(cfg, params, prompts, new))
+    assert (out["steps_ahead"], out["steps_discarded"]) == (new - 1, 0)
+
+
+@pytest.mark.parametrize("arch", SERVED)
+def test_eos_stops_each_row_and_the_call(arch):
+    cfg, params = _served(arch)
+    pool, new = _prompts(b=32, vocab=cfg.vocab), 8
+    full = _serve(cfg, params, pool, new)["completions"]
+    first = {}                          # token -> {row: step it first comes}
+    for r, row in enumerate(full):
+        for step, tok in enumerate(row):
+            first.setdefault(tok, {}).setdefault(r, step)
+    # an EOS two rows meet at different steps, both before the last
+    eos, rows = next(
+        (tok, (r0, r1)) for tok, at in sorted(first.items())
+        for r0, r1 in itertools.combinations(at, 2)
+        if at[r0] != at[r1] and max(at[r0], at[r1]) < new - 1)
+    at = first[eos]
+    other = next(r for r in range(len(pool)) if r not in at)
+    # with a row that never meets EOS the call runs to its end; without
+    # it, the call ends where the later row stops, dropping its queued step
+    for picked, early in ((rows + (other,), False), (rows, True)):
+        out = _serve(cfg, params, [pool[r] for r in picked], new, eos=eos)
+        assert out["completions"] == [full[r][:at[r] + 1] if r in at
+                                      else full[r] for r in picked]
+        assert out["steps_discarded"] == int(early)
+        assert out["steps_ahead"] == (max(at[r] for r in rows) + 1 if early
+                                      else new - 1)
+
+
+def test_numpy_stand_in_for_the_greedy_sampler_drives_the_loop(monkeypatch):
+    """The seam the benchmark's tests use: a stand-in for
+    ``greedy_argmax_multistream`` that returns numpy ids is looked up
+    when the server samples, and its ids are served."""
+    from repro.runtime import serve
+    cfg, params = _served("llama3-8b")
+    prompts, new = _prompts(vocab=cfg.vocab), 4
+    want = _serve(cfg, params, prompts, new)["completions"]
+    calls = []
+
+    def host_argmax(logits):
+        calls.append(np.shape(logits))
+        return np.asarray(logits, np.float32).argmax(-1)
+
+    monkeypatch.setattr(serve, "greedy_argmax_multistream", host_argmax)
+    assert _serve(cfg, params, prompts, new)["completions"] == want
+    assert calls == [(len(prompts), cfg.vocab)] * new
+
+    def first_row_worst(logits):
+        ids = np.asarray(logits, np.float32).argmax(-1)
+        ids[0] = np.asarray(logits, np.float32)[0].argmin()
+        return ids
+
+    monkeypatch.setattr(serve, "greedy_argmax_multistream", first_row_worst)
+    got = _serve(cfg, params, prompts, new)["completions"]
+    assert got[0] != want[0] and got[1:] == want[1:]
+
+
+def test_device_greedy_argmax_returns_device_int32_ids():
+    from repro.runtime.serve import greedy_argmax_multistream
+    logits = _logits(4, 300)
+    ids = greedy_argmax_multistream(jnp.asarray(logits, jnp.bfloat16))
+    assert isinstance(ids, jax.Array) and ids.dtype == jnp.int32
+    np.testing.assert_array_equal(
+        ids, np.asarray(jnp.asarray(logits, jnp.bfloat16),
+                        np.float32).argmax(-1))

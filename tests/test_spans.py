@@ -52,7 +52,8 @@ def test_generate_writes_the_span_tree(tmp_path):
     prompts = [np.arange(6) % 256, (np.arange(6) + 5) % 256]
     srv.generate(prompts)                           # compile outside the trace
     out, found = _traced(tmp_path, lambda: srv.generate(prompts))
-    assert set(out) == {"completions", "prefill_s", "decode_tok_per_s"}
+    assert set(out) == {"completions", "prefill_s", "decode_tok_per_s",
+                        "steps_ahead", "steps_discarded"}
     serve = [s for s in found if s[0].startswith("serve.")]
     (call,) = [s for s in serve if s[0] == "serve.generate"]
     assert isinstance(call[3]["call"], int)
@@ -61,28 +62,55 @@ def test_generate_writes_the_span_tree(tmp_path):
 
     (prefill,) = [s for s in serve if s[0] == "serve.prefill"]
     inner = [s for s in _inside(prefill, serve)]
-    assert _names(inner) == ["serve.forward", "serve.sample",
-                             "serve.readback"]
-    assert [s[3].get("phase") for s in inner[:2]] == ["prefill", "prefill"]
-    # the readback copies the sampled slots to the host, not the image
-    assert inner[2][3]["bytes"] == 4 * len(prompts)
-    # the host's clock agrees: first tokens on the host end the prefill
+    assert _names(inner) == ["serve.forward", "serve.sample"]
+    assert [s[3].get("phase") for s in inner] == ["prefill", "prefill"]
+    # the host's clock agrees: first tokens on the host come after the
+    # prefill's dispatch
     assert out["prefill_s"] * 1e9 >= prefill[2] - prefill[1]
     assert out["prefill_s"] * 1e9 <= call[2] - call[1]
 
     steps = [s for s in serve if s[0] == "serve.step"]
     assert [s[3]["step"] for s in steps] == list(range(new))
     assert [s[3]["active"] for s in steps] == [2] * new
-    for step in steps:
+    for i, step in enumerate(steps):
         assert step[1] >= prefill[2]
         inner = _inside(step, serve)
-        assert _names(inner) == ["serve.emit", "serve.forward",
-                                 "serve.sample", "serve.readback"]
-        assert [s[3].get("phase") for s in inner[1:3]] == ["decode",
-                                                           "decode"]
-        (sample,) = [s for s in inner if s[0] == "serve.sample"]
-        assert inner[3][3]["bytes"] == 4 * len(prompts)
-        assert "ntx.run" in _names(_inside(sample, found))
+        tail = ["serve.readback", "serve.emit"]
+        if i + 1 < new:                 # the next step's forward and sample
+            assert _names(inner) == ["serve.forward", "serve.sample"] + tail
+            assert [s[3].get("phase") for s in inner[:2]] == ["decode",
+                                                              "decode"]
+            # the greedy sampler is a device program, not a descriptor run
+            assert "ntx.run" not in _names(_inside(inner[1], found))
+        else:                           # the last token needs no forward
+            assert _names(inner) == tail
+        # the readback copies the sampled ids to the host, 4 B a row
+        (readback,) = [s for s in inner if s[0] == "serve.readback"]
+        assert readback[3]["bytes"] == 4 * len(prompts)
+
+
+def test_generate_reads_ids_one_step_behind(tmp_path):
+    """No host sync between one decode step and the next: the ids of
+    step i are read after the forward that consumes them is dispatched,
+    so step i+1 is queued on the device while the host reads."""
+    new = 4
+    srv = Server(CFG, Model(CFG).init(0),
+                 ServeConfig(max_seq=16, max_new_tokens=new, eos_token=-1))
+    prompts = [np.arange(5) % 256, (np.arange(5) + 9) % 256]
+    srv.generate(prompts)                           # compile outside the trace
+    out, found = _traced(tmp_path, lambda: srv.generate(prompts))
+    forwards = [s for s in found if s[0] == "serve.forward"]
+    readbacks = [s for s in found if s[0] == "serve.readback"]
+    # the prefill's forward, then one a step but the last
+    assert [s[3]["phase"] for s in forwards] == ["prefill"] + ["decode"] * (
+        new - 1)
+    assert len(readbacks) == new
+    # forwards[i + 1] makes the logits of token i + 1 from token i's ids
+    for i in range(new - 1):
+        assert readbacks[i][1] >= forwards[i + 1][1]
+    (call,) = [s for s in found if s[0] == "serve.generate"]
+    assert call[3]["steps_ahead"] == new - 1 == out["steps_ahead"]
+    assert call[3]["steps_discarded"] == 0 == out["steps_discarded"]
 
 
 def test_executor_plans_once_per_program(tmp_path):
