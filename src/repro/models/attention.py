@@ -21,7 +21,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ops
+from repro.kernels import mla_decode as mla_decode_kernel, ops
 from .common import (ArchConfig, Params, apply_rope, apply_mrope,
                      ctx_constrain_q, ctx_replicate_kv, dense_init,
                      yarn_attn_scale)
@@ -228,10 +228,13 @@ def mla_init_cache(cfg: ArchConfig, batch: int, seq: int, dtype) -> Params:
 
 
 def mla_decode(cfg: ArchConfig, p: Params, x: jnp.ndarray, pos,
-               cache: Params, fill: jnp.ndarray, absorbed: bool = False):
+               cache: Params, fill: jnp.ndarray, absorbed: bool = False,
+               layer=None):
     """Returns (out, {"c_kv": (b, s_new, r), "k_rope": (b, 1, s_new, dr)}).
     The absorbed form attends over the cache and the new entries as they
-    are; the expanded form over a copy of the cache with them written."""
+    are, its ``cache`` the kind's stacked caches and ``layer`` this
+    layer's index in them; the expanded form over a copy of the layer's
+    ``cache`` with them written."""
     dt = cfg.cdtype
     s = x.shape[1]
     q_nope, q_rope, c_kv_new, k_rope_new = _mla_qkv(cfg, p, x, pos)
@@ -239,7 +242,7 @@ def mla_decode(cfg: ArchConfig, p: Params, x: jnp.ndarray, pos,
     with jax.named_scope("mla.attend"):
         if absorbed:
             out = _mla_attend_absorbed(cfg, p, q_nope, q_rope, cache, new,
-                                       fill)
+                                       fill, layer)
         else:
             c = write_cache(cache, new, fill)
             out = _mla_attend(cfg, p, q_nope, q_rope, c["c_kv"].astype(dt),
@@ -247,8 +250,19 @@ def mla_decode(cfg: ArchConfig, p: Params, x: jnp.ndarray, pos,
     return out, new
 
 
+def mla_onepass_fits(cfg: ArchConfig, batch: int, s: int, seq: int,
+                     cache_dtype=jnp.bfloat16) -> bool:
+    """Whether the absorbed decode of ``s`` new tokens a row over a
+    ``batch`` x ``seq`` cache takes the one-pass kernel on a TPU
+    (``kernels/mla_decode.py``): one token, queries in the cache's
+    dtype, and a cache the kernel's blocks tile."""
+    return (s == 1 and jnp.dtype(cfg.cdtype) == jnp.dtype(cache_dtype)
+            and mla_decode_kernel.blocks(batch, seq, cfg.kv_lora_rank,
+                                         cfg.rope_head_dim) is not None)
+
+
 def _mla_attend_absorbed(cfg: ArchConfig, p: Params, q_nope, q_rope,
-                         cache: Params, new: Params, fill):
+                         stack: Params, new: Params, fill, layer):
     """Absorbed-matmul MLA decode (beyond-paper §Perf optimization).
 
     Instead of expanding the latent cache to per-head K/V (which costs
@@ -258,46 +272,94 @@ def _mla_attend_absorbed(cfg: ArchConfig, p: Params, q_nope, q_rope,
     query — the memory term drops by ~h x as well since the latent is read
     once instead of h expanded heads.
 
-    The keys are the cache's positions < ``fill`` and this step's entries
-    ``new``, causally, each read where it lies: the cache is an operand of
-    the scores and the values, and no copy of it is made.
+    The keys are layer ``layer`` of the kind's ``stack`` at positions <
+    ``fill`` and this step's entries ``new``, causally, each read where it
+    lies: no copy of the cache is made. Lowered for a TPU at shapes
+    ``mla_onepass_fits`` takes, one Pallas pass reads the layer's
+    positions < ``fill`` for the scores and the values alike; elsewhere
+    the jnp form makes one einsum of each over the layer's whole cache.
     """
     dt = cfg.cdtype
     b, h, s, dn = q_nope.shape
     r = cfg.kv_lora_rank
     dv = cfg.v_head_dim
-    skv = cache["c_kv"].shape[1]
-    c_kv, k_rope = cache["c_kv"].astype(dt), cache["k_rope"].astype(dt)
-    # this step's entries rounded as the cache will hold them
-    c_new, k_new = (new[n].astype(cache[n].dtype).astype(dt)
-                    for n in ("c_kv", "k_rope"))
 
     wuk = p["wuk"].astype(dt).reshape(r, h, dn)
     # q_lat[b,h,s,r] = q_nope . wuk^T  (absorb the key up-projection)
     q_lat = jnp.einsum("bhsd,rhd->bhsr", q_nope, wuk)
-    scale = _mla_scale(cfg)
-    # scores over the latent keys + the shared rope key, in float32 as
-    # the expanded form's
-    f32 = jnp.float32
 
-    def scores(c, k):
-        return (jnp.einsum("bhsr,bkr->bhsk", q_lat, c,
-                           preferred_element_type=f32)
-                + jnp.einsum("bhsd,bkd->bhsk", q_rope, k[:, 0],
-                             preferred_element_type=f32)) * scale
+    def jnp_form():
+        one = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+            a, layer, 0, keepdims=False), stack)
+        return _latent_jnp(cfg, q_lat, q_rope, one, new, fill)
 
-    old = jnp.where(jnp.arange(skv) < fill, scores(c_kv, k_rope), -jnp.inf)
-    causal = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
-    cur = jnp.where(causal, scores(c_new, k_new), -jnp.inf)
-    # one softmax over both key sets, each kept where it lies
-    m = jnp.maximum(old.max(-1, keepdims=True), cur.max(-1, keepdims=True))
-    e_old, e_cur = jnp.exp(old - m), jnp.exp(cur - m)
-    z = e_old.sum(-1, keepdims=True) + e_cur.sum(-1, keepdims=True)
-    o_lat = (jnp.einsum("bhsk,bkr->bhsr", (e_old / z).astype(dt), c_kv,
-                        preferred_element_type=f32)
-             + jnp.einsum("bhsk,bkr->bhsr", (e_cur / z).astype(dt), c_new,
-                          preferred_element_type=f32)).astype(dt)
+    c_kv = stack["c_kv"]
+    if mla_onepass_fits(cfg, b, s, c_kv.shape[2], c_kv.dtype):
+        o_lat = jax.lax.platform_dependent(tpu=lambda: _latent_onepass(
+            cfg, q_lat, q_rope, stack, new, fill, layer), default=jnp_form)
+    else:
+        o_lat = jnp_form()
     wuv = p["wuv"].astype(dt).reshape(r, h, dv)
     o = jnp.einsum("bhsr,rhd->bhsd", o_lat, wuv)        # absorb W_uv
     o = o.transpose(0, 2, 1, 3).reshape(b, s, h * dv)
     return o @ p["wo"].astype(dt)
+
+
+def _latent_scores(cfg: ArchConfig, q_lat, q_rope, c, k):
+    """Scores of the latent and rope queries against keys ``c`` (b, k, r)
+    and ``k`` (b, 1, k, dr), in float32 as the expanded form's."""
+    f32 = jnp.float32
+    return (jnp.einsum("bhsr,bkr->bhsk", q_lat, c, preferred_element_type=f32)
+            + jnp.einsum("bhsd,bkd->bhsk", q_rope, k[:, 0],
+                         preferred_element_type=f32)) * _mla_scale(cfg)
+
+
+def _new_entries(cfg: ArchConfig, cache: Params, new: Params):
+    """This step's entries rounded as the cache will hold them."""
+    return (new[n].astype(cache[n].dtype).astype(cfg.cdtype)
+            for n in ("c_kv", "k_rope"))
+
+
+def _latent_jnp(cfg: ArchConfig, q_lat, q_rope, cache: Params, new: Params,
+                fill):
+    """The attention in the latent space, (b, h, s, r): the scores and
+    the values each one einsum over one layer's whole cache."""
+    dt = cfg.cdtype
+    s = q_lat.shape[2]
+    skv = cache["c_kv"].shape[1]
+    c_kv, k_rope = cache["c_kv"].astype(dt), cache["k_rope"].astype(dt)
+    c_new, k_new = _new_entries(cfg, cache, new)
+    f32 = jnp.float32
+    old = jnp.where(jnp.arange(skv) < fill,
+                    _latent_scores(cfg, q_lat, q_rope, c_kv, k_rope),
+                    -jnp.inf)
+    causal = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
+    cur = jnp.where(causal, _latent_scores(cfg, q_lat, q_rope, c_new, k_new),
+                    -jnp.inf)
+    # one softmax over both key sets, each kept where it lies
+    m = jnp.maximum(old.max(-1, keepdims=True), cur.max(-1, keepdims=True))
+    e_old, e_cur = jnp.exp(old - m), jnp.exp(cur - m)
+    z = e_old.sum(-1, keepdims=True) + e_cur.sum(-1, keepdims=True)
+    return (jnp.einsum("bhsk,bkr->bhsr", (e_old / z).astype(dt), c_kv,
+                       preferred_element_type=f32)
+            + jnp.einsum("bhsk,bkr->bhsr", (e_cur / z).astype(dt), c_new,
+                         preferred_element_type=f32)).astype(dt)
+
+
+def _latent_onepass(cfg: ArchConfig, q_lat, q_rope, stack: Params,
+                    new: Params, fill, layer, interpret: bool = False):
+    """``_latent_jnp`` for one new token a row with the cache read once:
+    the one-pass kernel over layer ``layer`` of the kind's ``stack``, then
+    this step's own entry merged under the same softmax."""
+    dt = cfg.cdtype
+    acc, m_old, l_old = mla_decode_kernel.mla_decode_onepass(
+        q_lat[:, :, 0], q_rope[:, :, 0], stack["c_kv"], stack["k_rope"],
+        layer, fill, scale=_mla_scale(cfg), interpret=interpret)
+    c_new, k_new = _new_entries(cfg, stack, new)
+    cur = _latent_scores(cfg, q_lat, q_rope, c_new, k_new)[:, :, 0]
+    m = jnp.maximum(m_old, cur)                              # (b, h, 1)
+    a, e = jnp.exp(m_old - m), jnp.exp(cur - m)
+    o = (a * acc + jnp.einsum("bhk,bkr->bhr", e.astype(dt), c_new,
+                              preferred_element_type=jnp.float32)
+         ) / (a * l_old + e)
+    return o[:, :, None].astype(dt)

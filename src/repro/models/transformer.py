@@ -196,13 +196,14 @@ def _mixer_prefill(cfg: ArchConfig, kind: str, p, h, pos, cache_len: int):
 
 
 def _mixer_decode(cfg: ArchConfig, kind: str, p, h, pos, c, fill,
-                  absorbed_mla: bool):
+                  absorbed_mla: bool, layer=None):
     """(out, this step's entries): new positions for attention, whole
-    states for a state-space layer (they have no sequence axis)."""
+    states for a state-space layer (they have no sequence axis). ``c`` is
+    the layer's cache, or its kind's stack where ``layer`` is given."""
     if kind.startswith("attn"):
         if cfg.mla:
             return attn.mla_decode(cfg, p["mixer"], h, pos, c, fill,
-                                   absorbed=absorbed_mla)
+                                   absorbed=absorbed_mla, layer=layer)
         return attn.gqa_decode(cfg, p["mixer"], h, pos, c, fill)
     return ssm_mod.ssm_decode(cfg, p["mixer"], h, c)
 
@@ -372,7 +373,9 @@ def decode_step(cfg: ArchConfig, params: Params, tokens: jnp.ndarray,
     dynamic_update_slice a leaf stores a segment's entries in place.
     Written inside the scan, the stacks would be carried, and the compiler
     lays a carried stack out for the small write: a copy of every stack in
-    and out of the loop and of every slot it reads."""
+    and out of the loop and of every slot it reads. The absorbed MLA
+    mixer takes its kind's whole stack and the layer's index: its one-pass
+    kernel reads the layer where it lies, with no slice of the stack."""
     x = embed_tokens(cfg, params["embed"], tokens)
     pos = positions(cfg, {"tokens": tokens}) + fill
     layers = params["layers"]
@@ -381,11 +384,13 @@ def decode_step(cfg: ArchConfig, params: Params, tokens: jnp.ndarray,
     def layer(kind, idx, carry):
         x, load = carry
         p = _index_tree(layers[kind], idx)
-        c = _index_tree(cache[kind], idx)
+        whole = absorbed_mla and cfg.mla and kind.startswith("attn")
+        c = cache[kind] if whole else _index_tree(cache[kind], idx)
         x, new, _, load = _layer(
             cfg, kind, p, x,
             lambda h: _mixer_decode(cfg, kind, p, h, pos, c, fill,
-                                    absorbed_mla), 0.0, load)
+                                    absorbed_mla, idx if whole else None),
+            0.0, load)
         return (x, load), new
 
     # moe_load is None without MoE layers
@@ -410,6 +415,18 @@ def decode_step(cfg: ArchConfig, params: Params, tokens: jnp.ndarray,
     h = apply_norm(cfg, params["final_norm"], x)
     logits = unembed(cfg, params["embed"], h)
     return logits, cache
+
+
+def decode_onepass_layers(cfg: ArchConfig, batch: int, seq: int,
+                          platform: str, absorbed_mla: bool) -> int:
+    """How many layers of a one-token ``decode_step`` over a ``batch`` x
+    ``seq`` bf16 cache, lowered for ``platform``, take the one-pass MLA
+    kernel: every attention layer of an absorbed MLA step lowered for a
+    TPU at shapes the kernel takes, else none."""
+    if not (platform == "tpu" and absorbed_mla and cfg.mla
+            and attn.mla_onepass_fits(cfg, batch, 1, seq)):
+        return 0
+    return sum(k.startswith("attn") for k in layer_schedule(cfg)[0])
 
 
 def prefill(cfg: ArchConfig, params: Params, batch: Dict[str, Any],

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from repro.core import ExecutionPolicy, Executor, Program
 from repro.core.spans import install_gc_span, span
 from repro.models import ArchConfig, Model
+from repro.models.transformer import decode_onepass_layers
 
 
 @dataclasses.dataclass
@@ -274,7 +275,9 @@ class Server:
         one held expert received (counted on the device, read once at the
         end). Spans (``repro.core.spans``): ``serve.generate`` (stats
         ``call``, ``batch``, ``prompt_len``, ``steps_ahead``,
-        ``steps_discarded`` and the two MoE counts) holds
+        ``steps_discarded``, the two MoE counts, and
+        ``mla_onepass_layers``: the layers of the compiled decode step
+        that take the one-pass MLA kernel) holds
         ``serve.prefill`` and one ``serve.step`` (``step``, ``active``:
         rows not done) per token. The prefill holds a ``serve.forward``
         and a ``serve.sample`` (``phase`` prefill); a step holds the next
@@ -340,7 +343,10 @@ class Server:
                 load = np.asarray(cache["moe_load"])
                 counts.update(moe_assigned=int(load.sum()),
                               moe_max_expert_load=int(load.max()))
-            call.set_metadata(**counts)
+            device = next(iter(jax.tree.leaves(cache)[0].devices()))
+            call.set_metadata(**counts, mla_onepass_layers=(
+                decode_onepass_layers(self.cfg, b, scfg.max_seq,
+                                      device.platform, self.cfg.mla_absorb)))
         return {"completions": out,
                 "prefill_s": t1 - t0,
                 "decode_tok_per_s": ((ahead - discarded) * b / decode_s)

@@ -1,8 +1,9 @@
 """DeepSeek-V2's mechanisms in the model code: YaRN-scaled rope and softmax
 scale (against the published formula, written out here), the leading
 dense layer, the dropless MoE over the held experts (against a dense
-per-token sum), and the server's jitted decode (absorbed MLA, donated
-cache, MoE counters)."""
+per-token sum), the one-pass absorbed MLA decode kernel (in interpret
+mode, against the jnp form), and the server's jitted decode (absorbed
+MLA, donated cache, MoE counters)."""
 import math
 
 import numpy as np
@@ -12,9 +13,10 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
-from repro.models import Model, common, moe
+from repro.kernels import mla_decode
+from repro.models import Model, attention, common, moe
 from repro.models.attention import _mla_scale
-from repro.models.transformer import layer_schedule
+from repro.models.transformer import decode_onepass_layers, layer_schedule
 from repro.runtime import ServeConfig, Server
 
 FULL = configs.get("deepseek-v2-lite-16b")
@@ -189,6 +191,64 @@ def test_decode_matches_prefill_continuation(absorbed):
     # the cache is stored in bf16 while the direct forward attends in f32
     np.testing.assert_allclose(np.asarray(full), np.asarray(step[:, 0]),
                                rtol=2e-2, atol=2e-2)
+
+
+def _onepass_cfg():
+    """The reduced config with a latent of one 128-lane row, the
+    narrowest the one-pass kernel takes."""
+    return configs.get_reduced("deepseek-v2-lite-16b").scaled(
+        kv_lora_rank=128)
+
+
+BK = mla_decode.BLOCK_K
+
+
+@pytest.mark.parametrize("fill", [1, BK - 1, BK, BK + 1, 2 * BK - 1])
+def test_onepass_kernel_matches_the_jnp_absorbed_form(fill, monkeypatch):
+    """Layer 2 of a 3-layer stack of 16 rows (two blocks of rows) and two
+    blocks of positions: the kernel's form of ``_mla_attend_absorbed``,
+    taken as it is lowered for a TPU but in interpret mode, against the
+    jnp form it takes on the CPU."""
+    cfg = _onepass_cfg()
+    n_layers, b, seq, layer = 3, 16, 2 * BK, 2
+    ks = jax.random.split(jax.random.key(fill), 4)
+    p = attention.mla_params(cfg, ks[0])
+    x = jax.random.normal(ks[1], (b, 1, cfg.d_model), cfg.cdtype)
+    pos = jnp.full((b, 1), fill)
+    q_nope, q_rope, c_new, k_new = attention._mla_qkv(cfg, p, x, pos)
+    new = {"c_kv": c_new, "k_rope": k_new}
+    stack = {"c_kv": jax.random.normal(
+        ks[2], (n_layers, b, seq, cfg.kv_lora_rank), jnp.bfloat16),
+        "k_rope": jax.random.normal(
+            ks[3], (n_layers, b, 1, seq, cfg.rope_head_dim), jnp.bfloat16)}
+    args = (cfg, p, q_nope, q_rope, stack, new, jnp.int32(fill),
+            jnp.int32(layer))
+    want = attention._mla_attend_absorbed(*args)      # lowered for the CPU
+    assert attention.mla_onepass_fits(cfg, b, 1, seq)
+    onepass = attention._latent_onepass
+    monkeypatch.setattr(attention, "_latent_onepass",
+                        lambda *a: onepass(*a, interpret=True))
+    monkeypatch.setattr(jax.lax, "platform_dependent",
+                        lambda tpu, default: tpu())
+    got = attention._mla_attend_absorbed(*args)
+    want, got = np.asarray(want, np.float32), np.asarray(got, np.float32)
+    # the jnp form rounds normalised weights to bf16, the kernel the
+    # running ones: a bf16 ulp of the output apart at most
+    np.testing.assert_allclose(got, want, rtol=2e-2,
+                               atol=2e-2 * np.abs(want).max())
+
+
+def test_onepass_layers_counts_attention_layers_lowered_for_a_tpu():
+    cfg = _onepass_cfg()
+    assert decode_onepass_layers(cfg, 8, 2 * BK, "tpu", True) == \
+        cfg.n_layers
+    assert decode_onepass_layers(cfg, 8, 2 * BK, "cpu", True) == 0
+    assert decode_onepass_layers(cfg, 8, 2 * BK, "tpu", False) == 0
+    assert decode_onepass_layers(cfg, 8, BK + 8, "tpu", True) == 0
+    # at a latent narrower than 128 lanes the TPU lays the stack out so
+    # that the kernel would copy it: not taken
+    small = configs.get_reduced("deepseek-v2-lite-16b")
+    assert decode_onepass_layers(small, 8, 2 * BK, "tpu", True) == 0
 
 
 def test_server_decode_is_jit_decode_absorbed_and_donated():

@@ -113,6 +113,23 @@ def test_generate_reads_ids_one_step_behind(tmp_path):
     assert call[3]["steps_discarded"] == 0 == out["steps_discarded"]
 
 
+def test_generate_span_counts_the_onepass_mla_layers(tmp_path):
+    """``serve.generate`` says how many layers of its compiled decode step
+    take the one-pass MLA kernel: none where the step is lowered for the
+    CPU, MLA or not."""
+    from repro import configs
+    for cfg in (CFG, configs.get_reduced("deepseek-v2-lite-16b").scaled(
+            kv_lora_rank=128)):
+        srv = Server(cfg, Model(cfg).init(0),
+                     ServeConfig(max_seq=512, max_new_tokens=2,
+                                 eos_token=-1))
+        prompts = [np.arange(5) % 256, (np.arange(5) + 9) % 256]
+        srv.generate(prompts)                       # compile outside the trace
+        _, found = _traced(tmp_path / cfg.name, lambda: srv.generate(prompts))
+        (call,) = [s for s in found if s[0] == "serve.generate"]
+        assert call[3]["mla_onepass_layers"] == 0
+
+
 def test_executor_plans_once_per_program(tmp_path):
     p = Program()
     x = p.buffer(64, name="x")

@@ -135,3 +135,56 @@ CASES = {
 def test_kernel_compiles_for_v5e(name, one_chip):
     fn, shapes = CASES[name]
     assert _compile_has_kernel(fn, *shapes, sharding=one_chip), name
+
+
+def test_mla_decode_step_reads_the_latent_stacks_in_place(one_chip):
+    """``jit_decode`` of the reduced DeepSeek config (at a latent the
+    one-pass kernel takes) compiled for v5e: every MLA layer's attention
+    is the named kernel, and each latent stack reaches it whole, neither
+    sliced to the layer nor copied to another layout."""
+    import re
+    from repro import configs
+    from repro.kernels.mla_decode import BLOCK_K, KERNEL_NAME
+    from repro.models import transformer
+    from repro.runtime import Server, ServeConfig
+    cfg = configs.get_reduced("deepseek-v2-lite-16b").scaled(
+        kv_lora_rank=128)
+    b, seq = 8, 2 * BLOCK_K
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = shapes(jax.eval_shape(lambda: transformer.init_params(cfg, 0)))
+    cache = shapes(jax.eval_shape(
+        lambda: transformer.init_cache(cfg, b, seq)))
+    srv = Server(cfg, None, ServeConfig(max_seq=seq, max_new_tokens=2,
+                                        eos_token=-1))
+    ids, fill = shapes((jnp.zeros((b,), jnp.int32), jnp.int32(0)))
+    text = srv._decode.lower(params, ids, cache, fill,
+                             absorbed_mla=True).compile().as_text()
+    defs = dict(re.findall(r"^\s*(%[\w.-]+) = (.*)$", text, re.M))
+    calls = [v for k, v in defs.items() if k.startswith(f"%{KERNEL_NAME}")]
+    # one call in each segment's scan body: the dense layer and the MoE
+    # layers
+    assert len(calls) == 2
+    # the jnp form's scores over the whole cache are gone
+    h = cfg.n_heads
+    assert not re.search(rf"= f32\[{b},{h},(1,)?{seq}\]", text)
+    stacks = []
+    for call in calls:
+        operands = re.search(r"custom-call\(([^)]*)\)", call).group(1)
+        c_kv, k_rope = re.findall(r"%[\w.-]+", operands)[4:6]
+        for name in (c_kv, k_rope):
+            producer = defs[name]
+            assert not re.search(r"^%copy|dynamic-slice", name), name
+            assert not re.search(r"\bcopy\(|dynamic-slice", producer), (
+                name, producer[:200])
+        # the c_kv operand is a whole stack, (layers of the kind, b, seq,
+        # rank)
+        shape = re.match(r"bf16\[(\d+),(\d+),(\d+),(\d+)\]", defs[c_kv])
+        n, *rest = map(int, shape.groups())
+        assert rest == [b, seq, cfg.kv_lora_rank], defs[c_kv][:80]
+        stacks.append(n)
+    assert sorted(stacks) == sorted(
+        [cfg.first_dense_layers, cfg.n_layers - cfg.first_dense_layers])
